@@ -23,8 +23,15 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.launch.mesh import make_mesh
 
 ROWS = []
+
+
+def _host_mesh_env() -> dict:
+    """Env of the 8-host-device child processes: they emulate a mesh on
+    the CPU backend and must never load, or wait for, an accelerator."""
+    return {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
 
 
 def emit(name: str, us_per_call: float, derived: str):
@@ -105,7 +112,7 @@ def _train_resnet(batch: int, steps: int, *, lr=None, smoothing=0.1,
     from repro.train.step import make_eval_step, make_train_step
 
     cfg = get_config("resnet50").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = build_model(cfg)
     if lr is None:
         lr = linear_scaled_lr(16.0, batch) / 4     # tuned for the toy task
@@ -195,7 +202,7 @@ def _train_resnet_cfg(cfg, batch, steps, *, lr=None, smoothing=0.1,
     from repro.models.registry import build_model
     from repro.train import state as st
     from repro.train.step import make_eval_step, make_train_step
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = build_model(cfg)
     if lr is None:
         lr = linear_scaled_lr(16.0, batch) / 4
@@ -312,10 +319,11 @@ def bench_comm_bucketing(quick: bool):
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, time
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.core import bucketing, ddp
 from repro.core.compat import shard_map
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 ks = jax.random.split(jax.random.PRNGKey(0), 120)
 tree = {f"t{i}": jax.random.normal(ks[i], ((i % 7 + 1) * 96, 128))
         for i in range(120)}
@@ -335,11 +343,8 @@ for name, fn in [("naive", naive), ("bucketed", bucketed)]:
         jax.block_until_ready(f(tree))
     print(f"{name},{(time.perf_counter()-t0)/5*1e6:.0f}")
 """
-    # inherit the parent env: JAX_PLATFORMS=cpu must reach the child or
-    # jax probes for TPUs for minutes at import
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, timeout=600,
-                       env={**os.environ, "PYTHONPATH": "src"})
+                       text=True, timeout=600, env=_host_mesh_env())
     res = dict(line.split(",") for line in r.stdout.strip().splitlines()
                if "," in line)
     if "naive" in res and "bucketed" in res:
@@ -380,6 +385,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro import comm
 from repro.core import bucketing, ddp
@@ -391,7 +397,7 @@ ks = jax.random.split(jax.random.PRNGKey(0), N_TENSORS)
 tree = {f"t{i}": jax.random.normal(ks[i], ((i %% 7 + 1) * 96, 128))
         for i in range(N_TENSORS)}
 plan = bucketing.make_plan(tree, bucket_mb=1.0)
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 spec = jax.tree.map(lambda _: P(), tree)
 
 def mk(s):
@@ -416,7 +422,7 @@ for s in fns:
 """ % (n_tensors, rounds)
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=600,
-                       env={**os.environ, "PYTHONPATH": "src"})
+                       env=_host_mesh_env())
     res = dict(line.split(",") for line in r.stdout.strip().splitlines()
                if "," in line)
     if not res:
@@ -460,6 +466,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import CommConfig
 from repro.configs.shapes import InputShape
@@ -472,7 +479,7 @@ from repro.train.step import make_train_step
 
 SCHEDULES = %r
 ROUNDS = %d
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = get_config("resnet50").reduced()
 model = build_model(cfg)
 sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
@@ -500,7 +507,7 @@ for (sname, ov), ts in times.items():
     try:
         r = subprocess.run([sys.executable, "-c", script],
                            capture_output=True, text=True, timeout=900,
-                           env={**os.environ, "PYTHONPATH": "src"})
+                           env=_host_mesh_env())
     except subprocess.TimeoutExpired:
         emit("comm.overlap", (time.perf_counter() - t0) * 1e6,
              "FAILED: 900s subprocess timeout")
@@ -550,6 +557,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import CommConfig
 from repro.configs.shapes import InputShape
@@ -562,7 +570,7 @@ from repro.train.step import make_train_step
 
 SCHEDULES = %r
 ROUNDS = %d
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = get_config("resnet50").reduced()
 model = build_model(cfg)
 sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
@@ -597,7 +605,7 @@ for (sname, sh), ts in times.items():
     try:
         r = subprocess.run([sys.executable, "-c", script],
                            capture_output=True, text=True, timeout=900,
-                           env={**os.environ, "PYTHONPATH": "src"})
+                           env=_host_mesh_env())
     except subprocess.TimeoutExpired:
         emit("comm.shard_update", (time.perf_counter() - t0) * 1e6,
              "FAILED: 900s subprocess timeout")
@@ -790,7 +798,7 @@ def bench_ckpt_roundtrip(quick: bool):
     from repro.train.step import make_train_step
 
     model = build_model(get_config("resnet50").reduced())
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
                                          total_steps=10))
     cc = CommConfig(strategy="ring", bucket_mb=0.25, sharding="zero1")
@@ -839,7 +847,7 @@ def _guard_bench_setup():
         return _GUARD_CACHE["v"]
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=2,
                                          total_steps=10))
     cc = CommConfig(strategy="ring", bucket_mb=0.25, sharding="zero1")
@@ -877,7 +885,7 @@ def bench_guard_overhead(quick: bool):
     from repro.data.synthetic import make_batch_fn
     from repro.train import guard as guard_mod
     step_off, step_on, _, init = _guard_bench_setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bf = make_batch_fn(get_config("resnet50").reduced(),
                        InputShape("t", "train", 0, 32), seed=0, mesh=mesh)
     rounds = 7 if quick else 15
@@ -969,6 +977,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.core import bucketing, ddp
 from repro.core.compat import shard_map
@@ -976,7 +985,7 @@ from repro.obs import drift as obs_drift
 from repro.obs.trace import Tracer
 
 STEPS = 4
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 ks = jax.random.split(jax.random.PRNGKey(0), 12)
 tree = {f"t{i}": jax.random.normal(ks[i], ((i % 5 + 1) * 128, 128))
         for i in range(12)}
@@ -1011,7 +1020,7 @@ print("ring;" + json.dumps(obs_drift.measured_span_times(tr2)), flush=True)
     try:
         r = subprocess.run([sys.executable, "-c", script],
                            capture_output=True, text=True, timeout=600,
-                           env={**os.environ, "PYTHONPATH": "src"})
+                           env=_host_mesh_env())
     except subprocess.TimeoutExpired:
         emit("trace.drift", (time.perf_counter() - t0) * 1e6,
              "FAILED: 600s subprocess timeout")

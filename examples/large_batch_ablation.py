@@ -19,6 +19,7 @@ from repro.data.synthetic import make_batch_fn, prototype_imagenet
 from repro.models.registry import build_model
 from repro.train.state import init_state
 from repro.train.step import make_eval_step, make_train_step
+from repro.launch.mesh import make_mesh
 
 
 def run(cfg, model, mesh, *, batch, steps, opt, warmup, smoothing):
@@ -45,7 +46,7 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     args = ap.parse_args()
     cfg = get_config("resnet50").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = build_model(cfg)
 
     print(f"{'batch':>6} {'recipe':>22} {'eval_acc':>9} {'loss':>7}")

@@ -39,8 +39,6 @@ def ring_add_step(recv, chunks, k, *, interpret: bool = None):
     """``recv + chunks[k]`` as one fused VMEM pass. See module docstring."""
     n, c = chunks.shape
     assert c % CHUNK == 0 and recv.shape == (c,), (chunks.shape, recv.shape)
-    if interpret is None:
-        interpret = resolve_interpret()
     tiles = c // CHUNK
     recv2 = recv.reshape(tiles * SUB, LANE)
     chunks2 = chunks.reshape(n * tiles * SUB, LANE)
@@ -57,7 +55,7 @@ def ring_add_step(recv, chunks, k, *, interpret: bool = None):
             out_specs=pl.BlockSpec((SUB, LANE), lambda i, k: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((tiles * SUB, LANE), recv.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(k_arr, recv2, chunks2)
     return out.reshape(c)
 

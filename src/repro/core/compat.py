@@ -1,39 +1,27 @@
-"""jax version compatibility shims (container pins jax 0.4.37).
+"""``shard_map`` and mesh-axis helpers on the installed jax (0.9).
 
-Newer jax exposes ``jax.shard_map`` and ``jax.lax.axis_size``; 0.4.37 has
-neither. Everything under ``repro`` that needs them imports from here so a
-future jax upgrade is a one-file change.
+Everything under ``repro`` that needs them imports from here.
 
-* ``shard_map``  — resolves to ``jax.shard_map`` when present, else the
-  0.4.x ``jax.experimental.shard_map.shard_map``. ``check_rep`` defaults to
-  False: the comm schedules are built on ``ppermute``/dynamic indexing,
-  whose replication can't be statically inferred by the 0.4.x checker.
-* ``axis_size``  — ``jax.lax.axis_size`` when present, else ``psum(1, axis)``
-  which jax constant-folds to the static mesh-axis size (verified: returns a
-  Python int under shard_map tracing, so it is safe in static contexts such
-  as loop bounds and reshape dims).
+* ``shard_map`` — ``jax.shard_map`` with ``check_vma`` off by default: the
+  comm schedules are built on ``ppermute``/dynamic indexing, whose
+  per-device variance the checker cannot infer statically.
+* ``axis_size`` / ``axes_size`` — static size of one or several named mesh
+  axes inside ``shard_map`` tracing (Python ints, safe as loop bounds and
+  reshape dims).
 """
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.4.34 exposes it at top level... but not in 0.4.37's layout
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_rep)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name) -> int:
     """Static size of a named mesh axis, inside shard_map/pmap tracing."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return ax(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def axes_size(axes) -> int:

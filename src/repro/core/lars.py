@@ -200,10 +200,9 @@ def sharded_update_from_shards(p_shards, grad_shards, mom_shards, lr,
     trust = shard_trust_ratios(p_shards, grad_shards, segs, plan, cfg,
                                shard_axis=shard_axis)
     if update_kernel:
-        from repro.kernels.backend import resolve_interpret
         from repro.kernels.lars_update import lars_packed_update
-        mode = resolve_interpret() if interpret is None else interpret
-        upd = lambda *a, **kw: lars_packed_update(*a, interpret=mode, **kw)
+        upd = lambda *a, **kw: lars_packed_update(*a, interpret=interpret,
+                                                  **kw)
     else:
         from repro.kernels.ref import lars_packed_update as upd
     new_p, new_m = [], []

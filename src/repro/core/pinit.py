@@ -10,6 +10,7 @@ materializes only its own shard.
 """
 from __future__ import annotations
 
+import functools
 import zlib
 from typing import Any, Optional
 
@@ -70,13 +71,24 @@ def shardings(tree, mesh):
                         is_leaf=_is_pd)
 
 
-def materialize(tree, seed: int, mesh: Optional[Any] = None):
-    """Initialize all parameters, communication-free (see module docstring)."""
-    def build():
+@functools.lru_cache(maxsize=16)
+def _initializer(treedef, pds, mesh):
+    """The jitted initializer of one descriptor tree on one mesh. The seed
+    is its argument, so every seed and every later call reuses one
+    compiled program."""
+    tree = jax.tree_util.tree_unflatten(treedef, pds)
+
+    def build(seed):
         return jax.tree_util.tree_map_with_path(
             lambda path, pd: _init_leaf(pd, _leaf_key(seed, path)),
             tree, is_leaf=_is_pd)
 
     if mesh is None:
-        return jax.jit(build)()
-    return jax.jit(build, out_shardings=shardings(tree, mesh))()
+        return jax.jit(build)
+    return jax.jit(build, out_shardings=shardings(tree, mesh))
+
+
+def materialize(tree, seed: int, mesh: Optional[Any] = None):
+    """Initialize all parameters, communication-free (see module docstring)."""
+    pds, treedef = jax.tree_util.tree_flatten(tree, is_leaf=_is_pd)
+    return _initializer(treedef, tuple(pds), mesh)(jnp.int32(seed))

@@ -1,23 +1,21 @@
-"""Pallas execution-mode policy shared by every kernel wrapper.
+"""Pallas execution mode, decided by the platform alone.
 
-This container is CPU-only, so kernels run in interpret mode; on a real TPU
-backend they compile. ``REPRO_PALLAS_INTERPRET=0|1`` force-overrides either
-way (useful for debugging a compiled kernel in interpret mode on TPU, or
-asserting the compiled path in CI).
+Kernels compile on a TPU backend and run in interpret mode on every other
+backend (the CPU has no Mosaic compiler). Every kernel wrapper takes
+``interpret=None`` and resolves it here. A caller passes True/False only to
+pin the mode: tests that run a kernel body on the CPU, or a compile for a
+described TPU from a process whose backend is the CPU.
 """
 from __future__ import annotations
 
-import os
+from typing import Optional
 
 import jax
 
 
-def resolve_interpret(backend: str = None) -> bool:
-    """True -> run pallas_call in interpret mode for ``backend`` (default:
-    the current default jax backend)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    if backend is None:
-        backend = jax.default_backend()
-    return backend != "tpu"
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``interpret`` if given, else True unless the default backend is a
+    TPU."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"

@@ -12,9 +12,14 @@ Layout (produced by ``repro.core.bucketing``):
   seg_ids  : (n_chunks,) int32    — which tensor each chunk belongs to
                                      (scalar-prefetched: it drives the output
                                      block index_map)
-  out      : (n_tensors, 128) f32 — column 0 holds the sum of squares
+  out      : (n_tensors * 8, 128) f32 — one (8, 128) tile per tensor,
+                                     every element of which holds its sum
+                                     of squares (a tile is the smallest
+                                     block the TPU stores unmasked)
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bucketing import CHUNK
+from repro.kernels.backend import resolve_interpret
 
 SUB = 8
 LANE = 128
@@ -38,10 +44,11 @@ def _kernel(seg_ref, x_ref, out_ref):
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0, 0] += s
+    out_ref[...] += s
 
 
-def batched_sumsq(flat, seg_ids, n_tensors: int, *, interpret: bool = True):
+def batched_sumsq(flat, seg_ids, n_tensors: int, *,
+                  interpret: Optional[bool] = None):
     """See module docstring. Returns (n_tensors,) f32."""
     n_chunks = seg_ids.shape[0]
     assert flat.size == n_chunks * CHUNK
@@ -52,9 +59,9 @@ def batched_sumsq(flat, seg_ids, n_tensors: int, *, interpret: bool = True):
             num_scalar_prefetch=1,
             grid=(n_chunks,),
             in_specs=[pl.BlockSpec((SUB, LANE), lambda i, seg: (i, 0))],
-            out_specs=pl.BlockSpec((1, LANE), lambda i, seg: (seg[i], 0)),
+            out_specs=pl.BlockSpec((SUB, LANE), lambda i, seg: (seg[i], 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_tensors, LANE), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((n_tensors * SUB, LANE), jnp.float32),
+        interpret=resolve_interpret(interpret),
     )(seg_ids, x)
-    return out[:, 0]
+    return out[::SUB, 0]

@@ -16,11 +16,14 @@ cost walker can count its FLOPs analytically from the custom-call shapes.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
 
 NEG = -1e30
 
@@ -86,7 +89,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     n_q_heads: int = None, n_kv_heads: int = None,
-                    bq: int = 512, bk: int = 512, interpret: bool = True):
+                    bq: int = 512, bk: int = 512,
+                    interpret: Optional[bool] = None):
     """q: (BH, Sq, Dk); k/v: (BK, Sk, Dk/Dv) with BH = B*H, BK = B*K.
     Returns (BH, Sq, Dv)."""
     BH, Sq, Dk = q.shape
@@ -128,5 +132,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, Dv), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -1,13 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; on TPU the
-launchers pass interpret=False). Each wrapper has the identical signature
-pure-jnp fallback in ``repro.kernels.ref``.
+``interpret=None`` (the default) compiles the kernel on a TPU backend and
+interprets it elsewhere (each kernel resolves it through
+``backend.resolve_interpret``). Each wrapper has the identical signature
+pure-jnp oracle in ``repro.kernels.ref``.
 """
 from __future__ import annotations
 
 import functools
-from typing import List
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,11 +21,12 @@ from repro.kernels import smoothed_xent as _sx
 
 
 @functools.partial(jax.jit, static_argnames=("n_tensors", "interpret"))
-def batched_sumsq(flat, seg_ids, n_tensors: int, interpret: bool = True):
+def batched_sumsq(flat, seg_ids, n_tensors: int,
+                  interpret: Optional[bool] = None):
     return _bn.batched_sumsq(flat, seg_ids, n_tensors, interpret=interpret)
 
 
-def tree_norms(tree, *, plan=None, interpret: bool = True):
+def tree_norms(tree, *, plan=None, interpret: Optional[bool] = None):
     """Per-tensor L2 norms of a pytree via ONE batched-norm kernel launch
     (paper §III-B.2). Returns a pytree of scalars matching ``tree``."""
     if plan is None:
@@ -44,7 +46,7 @@ def tree_norms(tree, *, plan=None, interpret: bool = True):
 @functools.partial(jax.jit,
                    static_argnames=("lr", "momentum", "wd", "interpret"))
 def lars_packed_update(p, g, m, trust, seg_ids, *, lr, momentum, wd,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     return _lu.lars_packed_update(p, g, m, trust, seg_ids, lr=lr,
                                   momentum=momentum, wd=wd,
                                   interpret=interpret)
@@ -52,13 +54,13 @@ def lars_packed_update(p, g, m, trust, seg_ids, *, lr, momentum, wd,
 
 @functools.partial(jax.jit, static_argnames=("smoothing", "interpret"))
 def smoothed_xent_rows(logits, labels, smoothing: float = 0.1,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     return _sx.smoothed_xent_rows(logits, labels, smoothing=smoothing,
                                   interpret=interpret)
 
 
 def flash_attention_bshd(q, k, v, *, causal=True, window=0,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """(B,S,H,Dk)/(B,S,K,D*) layout wrapper around the flash kernel."""
     B, Sq, H, Dk = q.shape
     K, Dv = k.shape[2], v.shape[-1]
@@ -66,5 +68,6 @@ def flash_attention_bshd(q, k, v, *, causal=True, window=0,
     kf = k.transpose(0, 2, 1, 3).reshape(B * K, k.shape[1], Dk)
     vf = v.transpose(0, 2, 1, 3).reshape(B * K, v.shape[1], Dv)
     o = _fa.flash_attention(qf, kf, vf, causal=causal, window=window,
-                            n_q_heads=H, n_kv_heads=K, interpret=interpret)
+                            n_q_heads=H, n_kv_heads=K,
+                            interpret=interpret)
     return o.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)

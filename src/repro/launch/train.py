@@ -25,6 +25,7 @@ from repro.core import lars
 from repro.core.schedule import ScheduleConfig, linear_scaled_lr, \
     make_schedule
 from repro.data.synthetic import make_batch_fn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.registry import build_model
 from repro.obs import metrics as obs_metrics
@@ -37,6 +38,7 @@ WHERE = "repro/launch/train.py"
 
 
 def main(argv=None):
+    """Parse ``argv`` and train. Returns (final state, history)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -98,6 +100,9 @@ def main(argv=None):
     ap.add_argument("--weight-decay", type=float, default=5e-5)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--model-parallel", type=int, default=1)
+    ap.add_argument("--devices", type=int, default=None, metavar="N",
+                    help="build the mesh over the first N devices "
+                         "(default: every device jax reports)")
     ap.add_argument("--eval-every", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
@@ -138,6 +143,9 @@ def main(argv=None):
                          "composed with the run schedule (0 = off, the "
                          "trajectory-preserving setting)")
     ap.add_argument("--data", default="lcg", choices=["lcg", "uniform"])
+    ap.add_argument("--log-every", type=int, default=10, metavar="K",
+                    help="log (and keep in the history) every K-th step's "
+                         "metrics, and the last step's")
     ap.add_argument("--history-out", default=None)
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="attach the step-timeline tracer and write a "
@@ -149,6 +157,7 @@ def main(argv=None):
                          "stream + obs.* rows) to a JSONL file")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     reg = obs_metrics.default_registry()
     sink = (reg.add_sink(obs_metrics.JsonlSink(args.metrics))
             if args.metrics else None)
@@ -166,7 +175,7 @@ def _run(args, *, reg: obs_metrics.Registry,
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    mesh = make_local_mesh(args.model_parallel)
+    mesh = make_local_mesh(args.model_parallel, devices=args.devices)
     model = build_model(cfg)
 
     lr = args.lr if args.lr is not None else linear_scaled_lr(0.1, args.batch)
@@ -312,7 +321,8 @@ def _run(args, *, reg: obs_metrics.Registry,
     state, history = loop.train(
         state, train_step, batch_fn, steps=args.steps, eval_step=eval_step,
         eval_batch_fn=batch_fn, eval_every=args.eval_every,
-        ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every, seed=args.seed,
+        log_every=args.log_every, ckpt_dir=args.ckpt_dir,
+        ckpt_every=args.ckpt_every, seed=args.seed,
         keep_last_k=args.keep_last_k, step_timeout_s=args.step_timeout_s,
         max_step_retries=args.max_step_retries,
         comm_plan=getattr(train_step, "comm_plan", None),
@@ -338,7 +348,7 @@ def _run(args, *, reg: obs_metrics.Registry,
     if args.history_out:
         with open(args.history_out, "w") as f:
             json.dump(history, f, indent=1)
-    return history
+    return state, history
 
 
 if __name__ == "__main__":

@@ -118,7 +118,28 @@ def init_state(model, seed: int = 0, mesh=None, opt_kind: str = "lars",
     bn = None
     if model.bn_state_pd is not None:
         bn = pinit.materialize(model.bn_state_pd, seed, mesh)
-    return TrainState(jnp.zeros((), jnp.int32), params, mom, bn, shards)
+    state = TrainState(jnp.zeros((), jnp.int32), params, mom, bn, shards)
+    if mesh is None:
+        return state
+    # place every leaf where the step on this mesh returns it (the spec
+    # rules of step.sharded_call), so that the jitted step's second call
+    # reuses its first call's executable
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.comm.cost import shard_axis_size
+    rep = NamedSharding(mesh, P())
+    per_param = pinit.shardings(model.param_pd, mesh)
+    split = NamedSharding(mesh, P(shard_axis_size(mesh.axis_names,
+                                                  mesh.devices.shape)[0]))
+    if sharded_plan is not None:
+        mom_sh = jax.tree.map(lambda _: split, mom)
+    elif opt_kind == "lamb":
+        mom_sh = {"m": per_param, "v": per_param, "count": rep}
+    else:
+        mom_sh = per_param
+    return jax.device_put(state, TrainState(
+        rep, None if params is None else per_param, mom_sh,
+        None if bn is None else pinit.shardings(model.bn_state_pd, mesh),
+        None if shards is None else jax.tree.map(lambda _: split, shards)))
 
 
 def abstract_state(model) -> TrainState:

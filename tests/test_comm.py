@@ -20,6 +20,7 @@ from repro.comm.ring_kernel import ring_add_step
 from repro.core import bucketing, ddp
 from repro.core.compat import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -302,7 +303,7 @@ def test_backward_times_interpolates_measured_profile():
 # ------------------------------------------- 1-device degenerate meshes
 
 def _roundtrip_1dev(strategy):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jnp.arange(5000, dtype=jnp.float32),
             "b": jnp.ones((3,), jnp.float32)}
     plan = bucketing.make_plan(tree, bucket_mb=0.01)
@@ -324,7 +325,7 @@ def test_schedules_identity_on_1_device(strategy):
 @pytest.mark.parametrize("strategy", ["bucketed", "ring", "dbtree"])
 def test_overlap_identity_on_1_device(strategy):
     """The custom-vjp overlap wrap is grad-transparent on a trivial mesh."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jnp.arange(5000, dtype=jnp.float32),
             "b": jnp.ones((3,), jnp.float32)}
     plan = bucketing.make_plan(tree, bucket_mb=0.01)
@@ -372,23 +373,16 @@ def test_ring_kernel_parity_ragged_buckets(n, length):
     """Interpret-mode parity of the Pallas ring-step fold against the jnp
     reference on RAGGED bucket lengths — the ``_as_chunks(pad_to=CHUNK)``
     zero-padded chunk view the ring schedules actually feed it — at every
-    chunk index. Honors ``REPRO_PALLAS_INTERPRET``: with the override
-    forcing the compiled path on a non-TPU backend there is nothing to
-    run, so the test skips rather than mask the config."""
+    chunk index."""
     from repro.comm import primitives as prim
     from repro.comm.ring_kernel import kernel_step_fn
-    from repro.kernels.backend import resolve_interpret
-    interpret = resolve_interpret()
-    if not interpret and jax.default_backend() != "tpu":
-        pytest.skip("compiled Pallas path needs a TPU backend "
-                    "(REPRO_PALLAS_INTERPRET=0 on CPU)")
     key = jax.random.PRNGKey(17 * n + length)
     x = jax.random.normal(key, (length,), jnp.float32)
     chunks = prim._as_chunks(x, n, pad_to=bucketing.CHUNK)
     c = chunks.shape[1]
     assert c % bucketing.CHUNK == 0 and n * c >= length
     recv = jax.random.normal(jax.random.fold_in(key, 1), (c,), jnp.float32)
-    step = kernel_step_fn(interpret)
+    step = kernel_step_fn(interpret=True)
     for k in range(n):
         got = step(recv, chunks, jnp.int32(k))
         want = prim.default_step_fn(recv, chunks, jnp.int32(k))
@@ -413,6 +407,7 @@ EQUIV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro import comm
 from repro.core import bucketing, ddp
@@ -436,7 +431,7 @@ assert plan.n_buckets >= 3, plan.bucket_sizes
 spec = jax.tree.map(lambda _: P(), tree)
 
 for shape, axes in [((8,), ("data",)), ((2, 4), ("pod", "data"))]:
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
 
     def run(strategy, **kw):
         def fn(t):
@@ -461,7 +456,7 @@ for shape, axes in [((8,), ("data",)), ((2, 4), ("pod", "data"))]:
         print(f"OK {shape} {s} maxdiff={md:.1e}")
 
 # Pallas ring-step kernel path (small: interpret-mode kernels are slow)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 ktree = {"w": jax.random.normal(jax.random.PRNGKey(9), (2048,))}
 kplan = bucketing.make_plan(ktree)
 kspec = {"w": P()}
@@ -488,7 +483,7 @@ print("OK kernel-ring")
 from repro.comm.autotune import autotune
 
 for shape, axes in [((8,), ("data",)), ((2, 4), ("pod", "data"))]:
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     tuned = autotune(tree, schedule="psum", axes=axes,
                      sizes=shape, dtype_bytes=4,
                      candidates=(0.02, 0.05, 0.1))
@@ -554,10 +549,26 @@ def test_all_schedules_match_naive_8dev():
 
 # --------------------------- ZeRO-1 sharded update (subprocess, 8 devices)
 
+def _run_side_by_side(script, argvs, *, timeout):
+    """Run ``script`` on 8 host devices once per argument list, all at
+    once; returns each run's (stdout, stderr)."""
+    procs = [subprocess.Popen([sys.executable, "-c", script, *argv],
+                              stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True,
+                              env={**os.environ, "PYTHONPATH": "src"})
+             for argv in argvs]
+    try:
+        return [p.communicate(timeout=timeout) for p in procs]
+    finally:
+        for p in procs:
+            p.kill()
+
+
 SHARD_SCRIPT = r"""
-import os
+import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro import comm
 from repro.core import bucketing, ddp, lars
@@ -596,9 +607,14 @@ def rank(axes):
 # size-1 axis must not change which axis the hierarchical/2d_torus
 # schedules scatter over (shard_axis = innermost NON-trivial), or the AR
 # and RS-terminal forms sum in different orders and drift apart
-for shape, axes in [((8,), ("data",)), ((2, 4), ("pod", "data")),
-                    ((8, 1), ("data", "model"))]:
-    mesh = jax.make_mesh(shape, axes)
+# one mesh per process (argv[1] indexes MESHES), so the three can compile
+# side by side on separate cores
+MESHES = [((8,), ("data",)), ((2, 4), ("pod", "data")),
+          ((8, 1), ("data", "model"))]
+MESHES = [MESHES[int(i)] for i in sys.argv[1:]] or MESHES
+
+for shape, axes in MESHES:
+    mesh = make_mesh(shape, axes)
     n_sh = shape[axes.index("data")]
     sspec = tuple(P("data") for _ in range(plan.n_buckets))
 
@@ -670,9 +686,8 @@ def local_loss(p, r):
         s = s + jnp.sum(jnp.sin(x) * x)
     return s
 
-for shape, axes in [((8,), ("data",)), ((2, 4), ("pod", "data")),
-                    ((8, 1), ("data", "model"))]:
-    mesh = jax.make_mesh(shape, axes)
+for shape, axes in MESHES:
+    mesh = make_mesh(shape, axes)
     n_sh = shape[axes.index("data")]
     sspec = tuple(P("data") for _ in range(plan.n_buckets))
 
@@ -720,10 +735,9 @@ def test_shard_update_matches_replicated_8dev():
     update kernel — and the in-backward gradient-sink reduce-scatter
     hands back exactly the post-backward ``reduce_scatter_grads`` shards
     for every schedule on all three meshes."""
-    r = subprocess.run([sys.executable, "-c", SHARD_SCRIPT],
-                       capture_output=True, text=True, timeout=900,
-                       env={**os.environ, "PYTHONPATH": "src"})
-    assert "SHARD-OK" in r.stdout, (r.stdout[-2000:], r.stderr[-3000:])
+    for out, err in _run_side_by_side(SHARD_SCRIPT, [["0"], ["1"], ["2"]],
+                                      timeout=900):
+        assert "SHARD-OK" in out, (out[-2000:], err[-3000:])
 
 
 # ------------- fully-overlapped ZeRO-1 train-step equivalence matrix
@@ -734,6 +748,7 @@ SHARD_STEP_SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro import comm
 from repro.configs import get_config
 from repro.configs.base import CommConfig
@@ -746,8 +761,8 @@ from repro.train import state as st
 from repro.train.step import make_train_step
 
 MESH = sys.argv[1]
-mesh = (jax.make_mesh((8, 1), ("data", "model")) if MESH == "flat"
-        else jax.make_mesh((2, 4), ("pod", "data")))
+mesh = (make_mesh((8, 1), ("data", "model")) if MESH == "flat"
+        else make_mesh((2, 4), ("pod", "data")))
 cfg = get_config("resnet50").reduced()
 model = build_model(cfg)
 sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
@@ -771,7 +786,7 @@ def run(comm_cfg):
         assert step.shard_update is True
         assert step.gather_ahead == (step.gather == "ahead"
                                      and step.sharding == "zero1")
-    s = st.init_state(model, 0,
+    s = st.init_state(model, 0, mesh,
                       sharded_plan=step.bucket_plan if sharded else None,
                       n_shards=step.n_shards if sharded else 1,
                       materialize_params=step.sharding != "zero3",
@@ -797,100 +812,90 @@ def run(comm_cfg):
 # ('bucketed' = psum alias: exercised at the update level in SHARD_SCRIPT,
 # not worth two more ResNet compiles here)
 schedules = comm.available()
-assert schedules[-1] == "ring"          # extras below reuse the last pair
-for s in schedules:
-    base_s, base_m, base_p = run(
-        CommConfig(strategy=s, bucket_mb=1.0, wire_dtype="f32"))
-    sh_s, sh_m, sh_p = run(
-        CommConfig(strategy=s, bucket_mb=1.0, wire_dtype="f32",
-                   shard_update=True))
-    md = max(jax.tree.leaves(jax.tree.map(
-        lambda a, b: float(jnp.abs(a - b).max()), base_p, sh_p)))
-    ml = abs(float(base_m["loss"]) - float(sh_m["loss"]))
-    assert md <= 1e-6 and ml <= 1e-6, (MESH, s, md, ml)
-    print(f"OK shard-step {MESH} {s} maxdiff={md:.1e}")
+assert "ring" in schedules          # the oracle of every non-schedule cell
 
-# extra cells (flat mesh): autotuned plan, Pallas update kernel, and the
-# end-of-step gather issue point — against the ring oracle kept from the
-# loop's last iteration
+# The cells, in order; this process runs those whose index is PART modulo
+# NPARTS, so the parts can compile side by side on separate cores.
+PART, NPARTS = int(sys.argv[2]), int(sys.argv[3])
+cells = [(s, s, CommConfig(strategy=s, bucket_mb=1.0, wire_dtype="f32",
+                           shard_update=True)) for s in schedules]
 if MESH == "flat":
-    for tag, cc in [
-        ("auto", CommConfig(strategy="ring", bucket_mb="auto",
-                            wire_dtype="f32", shard_update=True)),
-        ("kernel", CommConfig(strategy="ring", bucket_mb=1.0,
-                              wire_dtype="f32", shard_update=True,
-                              update_kernel=True)),
-        ("gather-at-end", CommConfig(strategy="ring", bucket_mb=1.0,
-                                     wire_dtype="f32", shard_update=True,
-                                     gather_ahead=False)),
-    ]:
-        sh_s, sh_m, sh_p = run(cc)
-        md = max(jax.tree.leaves(jax.tree.map(
-            lambda a, b: float(jnp.abs(a - b).max()), base_p, sh_p)))
-        ml = abs(float(base_m["loss"]) - float(sh_m["loss"]))
-        assert md <= 1e-6 and ml <= 1e-6, (tag, md, ml)
-        if tag == "gather-at-end":
-            # without gather-ahead the state's params copy is fresh (the
-            # step-end gather): it must equal the shards exactly (f32 wire)
-            pd = max(jax.tree.leaves(jax.tree.map(
-                lambda a, b: float(jnp.abs(a - b).max()),
-                sh_s.params, sh_p)))
-            assert pd == 0.0, pd
-        print(f"OK shard-step flat ring/{tag} maxdiff={md:.1e}")
-
-# ZeRO-3 cells — against the ring fp32 oracle kept from the loop's last
-# iteration. The jit-gather machinery is schedule-independent (the
+    # autotuned plan, Pallas update kernel, end-of-step gather issue point
+    cells += [
+        ("ring/auto", "ring",
+         CommConfig(strategy="ring", bucket_mb="auto", wire_dtype="f32",
+                    shard_update=True)),
+        ("ring/kernel", "ring",
+         CommConfig(strategy="ring", bucket_mb=1.0, wire_dtype="f32",
+                    shard_update=True, update_kernel=True)),
+        ("ring/gather-at-end", "ring",
+         CommConfig(strategy="ring", bucket_mb=1.0, wire_dtype="f32",
+                    shard_update=True, gather_ahead=False)),
+    ]
+# ZeRO-3 cells. The jit-gather machinery is schedule-independent (the
 # per-group AG is prim.ring_all_gather regardless of the RS schedule, and
 # the RS side is exactly the per-schedule-verified ZeRO-1 path), so one
 # per-group cell per mesh covers it; flat adds the retained-gather and
 # non-overlapped variants
-z3_cells = [("per_group", CommConfig(strategy="ring", bucket_mb=1.0,
-                                     wire_dtype="f32", sharding="zero3"))]
+cells.append(("zero3/per_group", "ring",
+              CommConfig(strategy="ring", bucket_mb=1.0, wire_dtype="f32",
+                         sharding="zero3")))
 if MESH == "flat":
-    z3_cells += [
-        ("retain", CommConfig(strategy="ring", bucket_mb=1.0,
-                              wire_dtype="f32", sharding="zero3",
-                              gather="ahead")),
-        ("no-overlap", CommConfig(strategy="ring", bucket_mb=1.0,
-                                  wire_dtype="f32", sharding="zero3",
-                                  overlap=False)),
+    cells += [
+        ("zero3/retain", "ring",
+         CommConfig(strategy="ring", bucket_mb=1.0, wire_dtype="f32",
+                    sharding="zero3", gather="ahead")),
+        ("zero3/no-overlap", "ring",
+         CommConfig(strategy="ring", bucket_mb=1.0, wire_dtype="f32",
+                    sharding="zero3", overlap=False)),
     ]
-for tag, cc in z3_cells:
+# ZeRO-2 + split-leaf cells (flat mesh). 0.25 MB f32 buckets split 7 of
+# the reduced ResNet's conv leaves across bucket boundaries, so the
+# split-aware packing, the tensor-id segment maps (LARS trust from
+# cross-bucket partial norms), the chained in-backward collectives, and
+# zero3's piece-wise jit gather all sit on the verified <=1e-6 path
+if MESH == "flat":
+    cells += [
+        ("zero2", "ring",
+         CommConfig(strategy="ring", bucket_mb=1.0, wire_dtype="f32",
+                    sharding="zero2")),
+        ("zero2-split", "ring",
+         CommConfig(strategy="ring", bucket_mb=0.25, wire_dtype="f32",
+                    sharding="zero2")),
+        ("zero3-split", "ring",
+         CommConfig(strategy="ring", bucket_mb=0.25, wire_dtype="f32",
+                    sharding="zero3")),
+    ]
+
+oracles = {}
+
+def oracle(strategy):
+    # the same-schedule replicated fp32 run a sharded cell must match
+    if strategy not in oracles:
+        oracles[strategy] = run(CommConfig(strategy=strategy, bucket_mb=1.0,
+                                           wire_dtype="f32"))
+    return oracles[strategy]
+
+for tag, strategy, cc in cells[PART::NPARTS]:
+    if "split" in tag:
+        import repro.core.bucketing as _bk
+        _plan = _bk.make_plan(model.param_pd, bucket_mb=0.25, dtype_bytes=4)
+        assert any(sl.elem_offset for sl in _plan.slots), \
+            "split cell does not split any leaf"
+    base_s, base_m, base_p = oracle(strategy)
     sh_s, sh_m, sh_p = run(cc)
     md = max(jax.tree.leaves(jax.tree.map(
         lambda a, b: float(jnp.abs(a - b).max()), base_p, sh_p)))
     ml = abs(float(base_m["loss"]) - float(sh_m["loss"]))
     assert md <= 1e-6 and ml <= 1e-6, (MESH, tag, md, ml)
-    print(f"OK shard-step {MESH} zero3/{tag} maxdiff={md:.1e}")
-
-# ZeRO-2 + split-leaf cells (flat mesh) — against the same ring fp32
-# oracle. 0.25 MB f32 buckets split 7 of the reduced ResNet's conv
-# leaves across bucket boundaries, so the split-aware packing, the
-# tensor-id segment maps (LARS trust from cross-bucket partial norms),
-# the chained in-backward collectives, and zero3's piece-wise jit
-# gather all sit on the verified <=1e-6 path
-if MESH == "flat":
-    for tag, cc in [
-        ("zero2", CommConfig(strategy="ring", bucket_mb=1.0,
-                             wire_dtype="f32", sharding="zero2")),
-        ("zero2-split", CommConfig(strategy="ring", bucket_mb=0.25,
-                                   wire_dtype="f32", sharding="zero2")),
-        ("zero3-split", CommConfig(strategy="ring", bucket_mb=0.25,
-                                   wire_dtype="f32", sharding="zero3")),
-    ]:
-        if "split" in tag:
-            import repro.core.bucketing as _bk
-            _plan = _bk.make_plan(model.param_pd, bucket_mb=0.25,
-                                  dtype_bytes=4)
-            assert any(sl.elem_offset for sl in _plan.slots), \
-                "split cell does not split any leaf"
-        sh_s, sh_m, sh_p = run(cc)
-        md = max(jax.tree.leaves(jax.tree.map(
-            lambda a, b: float(jnp.abs(a - b).max()), base_p, sh_p)))
-        ml = abs(float(base_m["loss"]) - float(sh_m["loss"]))
-        assert md <= 1e-6 and ml <= 1e-6, (MESH, tag, md, ml)
-        print(f"OK shard-step {MESH} {tag} maxdiff={md:.1e}")
-print("STEP-MATRIX-OK")
+    if tag == "ring/gather-at-end":
+        # without gather-ahead the state's params copy is fresh (the
+        # step-end gather): it must equal the shards exactly (f32 wire)
+        pd = max(jax.tree.leaves(jax.tree.map(
+            lambda a, b: float(jnp.abs(a - b).max()), sh_s.params, sh_p)))
+        assert pd == 0.0, pd
+    print(f"OK shard-step {MESH} {tag} maxdiff={md:.1e}")
+print(f"STEP-MATRIX-OK {len(cells[PART::NPARTS])} of {len(cells)}")
 """
 
 
@@ -914,13 +919,19 @@ def test_sharded_step_matrix_8dev(mesh_tag):
     split-leaf cells (0.25 MB buckets split 7 conv leaves across bucket
     boundaries) for both zero2 and zero3, all on the same <=1e-6 bar.
     Slow: every cell is a full ResNet compile on the 8-device CPU mesh
-    (~70 s each; 19 cells flat, 11 pod) — hence the wide timeout and the
-    per-mesh parametrization."""
-    r = subprocess.run([sys.executable, "-c", SHARD_STEP_SCRIPT, mesh_tag],
-                       capture_output=True, text=True, timeout=2700,
-                       env={**os.environ, "PYTHONPATH": "src"})
-    assert "STEP-MATRIX-OK" in r.stdout, (r.stdout[-2000:],
-                                          r.stderr[-3000:])
+    (~45 s each; 19 compiles flat and 11 pod in one process), so the
+    cells are split over concurrent subprocesses (5 flat, 3 pod), each
+    compiling the oracles its cells need."""
+    n_parts = 5 if mesh_tag == "flat" else 3
+    outs = _run_side_by_side(
+        SHARD_STEP_SCRIPT,
+        [[mesh_tag, str(i), str(n_parts)] for i in range(n_parts)],
+        timeout=2700)
+    cells = 0
+    for out, err in outs:
+        assert "STEP-MATRIX-OK" in out, (out[-2000:], err[-3000:])
+        cells += int(out.split("STEP-MATRIX-OK ")[1].split()[0])
+    assert cells == int(out.split(" of ")[-1]), outs
 
 
 # ------------------------------------------------------------- autotuner
@@ -990,7 +1001,7 @@ def test_shard_update_train_step_1_device():
 
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
                                          total_steps=4))
     step = make_train_step(model, lars.OptConfig(kind="lars"), sched,
@@ -1032,7 +1043,7 @@ def test_train_step_resolves_auto_bucket_mb():
 
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
                                          total_steps=4))
     step = make_train_step(model, lars.OptConfig(kind="lars"), sched,
@@ -1290,6 +1301,6 @@ def test_plan_for_facade_assembles_commplan():
     assert isinstance(pa.bucket_mb, float)
     assert pa.requested_bucket_mb == "auto"
     # a real Mesh works too
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pm = plan_for(cc, mesh, tree)
     assert pm.mesh_axes == ("data", "model") and pm.n_shards == 1

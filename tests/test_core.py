@@ -172,6 +172,29 @@ def test_pinit_deterministic_and_path_dependent():
     assert not np.allclose(p1["a"], p3["a"])          # different seeds
 
 
+def test_pinit_compiles_once_for_every_seed():
+    """The seed is an argument of the compiled initializer: new seeds and
+    repeated calls reuse one program, which gives what the seed gives as
+    a constant."""
+    tree = {"a": PD((48, 24)), "b": {"c": PD((24,), init="zeros")}}
+    compiled = []
+
+    def on_event(event, seconds, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        out = [pinit.materialize(tree, seed=s) for s in (0, 3, 3, 100000)]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiled.count("jit(build)") == 1, compiled
+    np.testing.assert_array_equal(out[1]["a"], out[2]["a"])
+    want = pinit._init_leaf(tree["a"], pinit._leaf_key(
+        100000, (jax.tree_util.DictKey("a"),)))
+    np.testing.assert_array_equal(out[3]["a"], want)
+
+
 def test_cast_to_compute_leaves_ints_alone():
     tree = {"w": jnp.ones((2,), jnp.float32), "i": jnp.ones((2,), jnp.int32)}
     out = cast_to_compute(tree)

@@ -27,6 +27,7 @@ from repro.train import elastic, faults, loop
 from repro.train import state as st
 from repro.train.state import TrainState
 from repro.train.step import make_train_step
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -37,7 +38,7 @@ pytestmark = pytest.mark.tier1
 def _mk_sharded_step(bucket_mb=0.25, wire="bf16", sharding=None):
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.5, warmup_steps=1,
                                          total_steps=10))
     if sharding is None:
@@ -179,7 +180,7 @@ def test_zero3_elastic_roundtrip_params_none(tmp_path):
     assert step_a.comm_plan.sharding == "zero3"
     assert step_a.comm_plan.gather == "per_group"
     bf = make_batch_fn(cfg, InputShape("t", "train", 0, 8), mesh=mesh)
-    s = st.init_state(model, 0, sharded_plan=step_a.bucket_plan,
+    s = st.init_state(model, 0, mesh, sharded_plan=step_a.bucket_plan,
                       n_shards=step_a.n_shards, materialize_params=False)
     assert s.params is None
     f_a = jax.jit(step_a)
@@ -556,7 +557,7 @@ def test_elastic_resume_across_bucket_plans(tmp_path):
     cfg, model, mesh, step_a = _mk_sharded_step(bucket_mb=0.25)
     f_a = jax.jit(step_a)
     bf = make_batch_fn(cfg, InputShape("t", "train", 0, 8), mesh=mesh)
-    s = st.init_state(model, 0, sharded_plan=step_a.bucket_plan,
+    s = st.init_state(model, 0, mesh, sharded_plan=step_a.bucket_plan,
                       n_shards=step_a.n_shards)
     for _ in range(2):
         s, _ = f_a(s, bf(s.step))
@@ -655,6 +656,7 @@ ELASTIC_SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import CommConfig
 from repro.configs.shapes import InputShape
@@ -669,7 +671,7 @@ from repro.train.step import make_train_step
 
 ROLE, DIR, K = {role!r}, {d!r}, 2
 NDEV = {ndev}
-mesh = jax.make_mesh((NDEV, 1), ("data", "model"))
+mesh = make_mesh((NDEV, 1), ("data", "model"))
 # the LM family: LayerNorm is per-example, so the math is device-count
 # invariant (ResNet's per-device BN batch stats are not)
 cfg = get_config("qwen1.5-0.5b").reduced()

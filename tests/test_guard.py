@@ -27,6 +27,7 @@ from repro.train import faults, guard, loop
 from repro.train import state as st
 from repro.train.state import TrainState
 from repro.train.step import make_train_step
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -42,7 +43,7 @@ def _guarded_setup():
     if not _CACHE:
         cfg = get_config("resnet50").reduced()
         model = build_model(cfg)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=2,
                                              total_steps=10))
         cc = CommConfig(strategy="ring", bucket_mb=0.25, sharding="zero1")

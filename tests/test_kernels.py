@@ -20,7 +20,8 @@ def test_batched_sumsq(n_chunks, n_tensors, dtype):
     seg = np.sort(np.arange(n_chunks) % n_tensors).astype(np.int32)
     flat = jax.random.normal(jax.random.PRNGKey(n_chunks),
                              (n_chunks * CHUNK,)).astype(dtype)
-    got = ops.batched_sumsq(flat, jnp.asarray(seg), n_tensors)
+    got = ops.batched_sumsq(flat, jnp.asarray(seg), n_tensors,
+                            interpret=True)
     want = ref.batched_sumsq(flat, jnp.asarray(seg), n_tensors)
     np.testing.assert_allclose(got, want, rtol=2e-3)
 
@@ -37,7 +38,8 @@ def test_lars_packed_update(n_chunks, n_tensors, lr, mu, wd):
     trust = jnp.abs(jax.random.normal(jax.random.fold_in(k, 3),
                                       (n_tensors,)))
     got_p, got_m = ops.lars_packed_update(p, g, m, trust, jnp.asarray(seg),
-                                          lr=lr, momentum=mu, wd=wd)
+                                          lr=lr, momentum=mu, wd=wd,
+                                          interpret=True)
     want_p, want_m = ref.lars_packed_update(p, g, m, trust, jnp.asarray(seg),
                                             lr=lr, momentum=mu, wd=wd)
     np.testing.assert_allclose(got_p, want_p, rtol=1e-5, atol=1e-6)
@@ -87,7 +89,8 @@ def test_lars_packed_update_kernel_on_real_bucket_layout():
                                                     dtype=jnp.float32))
     seg = jnp.asarray(bucketing.segment_ids(plan))
     got_p, got_m = ops.lars_packed_update(p_buf, g_buf, m_buf, trust, seg,
-                                          lr=lr, momentum=mu, wd=wd)
+                                          lr=lr, momentum=mu, wd=wd,
+                                          interpret=True)
     sizes = list(plan.bucket_sizes)
     offs = np.concatenate([[0], np.cumsum(sizes)])
     got_p_tree = bucketing.unpack(
@@ -135,13 +138,13 @@ def test_lars_packed_update_kernel_sharded_layout(n_shards):
         c = bucketing.shard_elems(plan.bucket_sizes[b], n_shards)
         full_p, full_m = ops.lars_packed_update(
             p, g, m, trust, jnp.asarray(seg_maps[b].reshape(-1)),
-            lr=0.1, momentum=0.9, wd=1e-4)
+            lr=0.1, momentum=0.9, wd=1e-4, interpret=True)
         for s in range(n_shards):
             sh_p, sh_m = ops.lars_packed_update(
                 p[s * c:(s + 1) * c], g[s * c:(s + 1) * c],
                 m[s * c:(s + 1) * c], trust,
                 jnp.asarray(seg_maps[b][s]), lr=0.1, momentum=0.9,
-                wd=1e-4)
+                wd=1e-4, interpret=True)
             np.testing.assert_allclose(sh_p, full_p[s * c:(s + 1) * c],
                                        rtol=1e-6, atol=1e-7)
             np.testing.assert_allclose(sh_m, full_m[s * c:(s + 1) * c],
@@ -149,13 +152,13 @@ def test_lars_packed_update_kernel_sharded_layout(n_shards):
 
 
 @pytest.mark.parametrize("T,V", [(8, 512), (64, 1000), (128, 4096),
-                                 (256, 2048), (16, 333)])
+                                 (256, 2048), (16, 333), (264, 5000)])
 @pytest.mark.parametrize("smoothing", [0.0, 0.1])
 def test_smoothed_xent(T, V, smoothing):
     k = jax.random.PRNGKey(T + V)
     logits = 4.0 * jax.random.normal(k, (T, V))
     labels = jax.random.randint(jax.random.fold_in(k, 1), (T,), 0, V)
-    got = ops.smoothed_xent_rows(logits, labels, smoothing)
+    got = ops.smoothed_xent_rows(logits, labels, smoothing, interpret=True)
     want = ref.smoothed_xent_rows(logits, labels, smoothing=smoothing)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -164,7 +167,7 @@ def test_smoothed_xent_bf16_logits():
     k = jax.random.PRNGKey(9)
     logits = (4.0 * jax.random.normal(k, (32, 512))).astype(jnp.bfloat16)
     labels = jax.random.randint(jax.random.fold_in(k, 1), (32,), 0, 512)
-    got = ops.smoothed_xent_rows(logits, labels, 0.1)
+    got = ops.smoothed_xent_rows(logits, labels, 0.1, interpret=True)
     want = ref.smoothed_xent_rows(logits.astype(jnp.float32), labels,
                                   smoothing=0.1)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
@@ -176,7 +179,7 @@ def test_tree_norms_matches_per_tensor():
             "b": jnp.full((7,), 2.0),
             "nested": {"x": jax.random.normal(jax.random.fold_in(k, 1),
                                               (1025,))}}
-    got = ops.tree_norms(tree)
+    got = ops.tree_norms(tree, interpret=True)
     want = jax.tree.map(lambda x: jnp.linalg.norm(x.astype(jnp.float32)),
                         tree)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5),
@@ -214,7 +217,8 @@ def test_flash_attention_vs_oracle(B, S, H, K, Dk, Dv, causal, window):
     q = jax.random.normal(kq, (B, S, H, Dk))
     k = jax.random.normal(jax.random.fold_in(kq, 1), (B, S, K, Dk))
     v = jax.random.normal(jax.random.fold_in(kq, 2), (B, S, K, Dv))
-    got = flash_attention_bshd(q, k, v, causal=causal, window=window)
+    got = flash_attention_bshd(q, k, v, causal=causal, window=window,
+                               interpret=True)
     want = chunked_attention(q, k, v, q_offset=0, causal=causal,
                              window=window, chunk=32)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
@@ -229,7 +233,7 @@ def test_flash_attention_bf16():
                           (2, 64, 2, 32)).astype(jnp.bfloat16)
     v = jax.random.normal(jax.random.fold_in(kq, 2),
                           (2, 64, 2, 32)).astype(jnp.bfloat16)
-    got = flash_attention_bshd(q, k, v, causal=True)
+    got = flash_attention_bshd(q, k, v, causal=True, interpret=True)
     want = chunked_attention(q, k, v, q_offset=0, causal=True, chunk=32)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),

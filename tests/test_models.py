@@ -13,6 +13,7 @@ from repro.models import mamba as mb
 from repro.models import xlstm as xl
 from repro.models.attention import chunked_attention
 from repro.models.common import rms_norm, rope
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -138,7 +139,7 @@ def test_moe_capacity_drop_rate_reasonable():
     """At init (near-uniform router) the drop rate at cf=1.25 stays small."""
     from repro.models import moe as moem
     cfg = get_config("qwen2-moe-a2.7b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pd = moem.moe_pd(cfg)
     p = pinit.materialize(pd, seed=0)
     x = 0.1 * jax.random.normal(jax.random.PRNGKey(0), (4, 64, cfg.d_model))

@@ -30,6 +30,7 @@ from repro.obs import trace as obs_trace
 from repro.obs.metrics import (Event, JsonlSink, MemorySink, Registry,
                                StdoutSink)
 from repro.obs.trace import Span, Tracer
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -228,7 +229,7 @@ def test_mark_is_noop_without_tracer():
 def test_traced_allreduce_spans_1dev():
     """End-to-end probe plumbing on the in-process 1-device mesh: one
     ``ar[bi]`` span per bucket per step, inside the step window."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"a": jnp.ones((3000,)), "b": jnp.ones((3000,))}
     plan = bucketing.make_plan(tree, bucket_mb=0.005)  # several buckets
     assert plan.n_buckets >= 2
@@ -389,6 +390,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import CommConfig
 from repro.configs.shapes import InputShape
@@ -400,7 +402,7 @@ from repro.obs.trace import Tracer
 from repro.train import state as st
 from repro.train.step import make_train_step
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = get_config("resnet50").reduced()
 model = build_model(cfg)
 sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,

@@ -20,6 +20,7 @@ from repro.models.registry import build_model
 from repro.train import checkpoint as ckpt
 from repro.train import state as st
 from repro.train.step import make_eval_step, make_train_step
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.tier1
 
@@ -28,19 +29,67 @@ def _train(arch, steps, *, opt="lars", lr=2.0, comm="xla", mesh=None,
            batch=8, seq=64, warmup=None):
     cfg = get_config(arch).reduced()
     model = build_model(cfg)
-    mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh or make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(
         base_lr=lr, warmup_steps=warmup if warmup is not None else steps // 8,
         total_steps=steps, decay="poly2"))
     step = jax.jit(make_train_step(model, lars.OptConfig(kind=opt), sched,
                                    mesh=mesh, comm=comm))
     bf = make_batch_fn(cfg, InputShape("t", "train", seq, batch), mesh=mesh)
-    s = st.init_state(model, 0, opt_kind=opt)
+    s = st.init_state(model, 0, mesh, opt_kind=opt)
     losses = []
     for _ in range(steps):
         s, m = step(s, bf(s.step))
         losses.append(float(m["loss"]))
     return losses, s
+
+
+@pytest.mark.parametrize("extra", [[], ["--comm", "ring", "--sharding",
+                                        "zero1"]], ids=["xla", "ring-zero1"])
+def test_launcher_compiles_each_step_program_once(extra):
+    """Steps 1.. reuse step 0's executables: ``init_state`` places the
+    state where the step returns it, so neither the train step nor the
+    batch function (fed ``state.step``) compiles a second time."""
+    from repro.launch import train as launcher
+    compiled = []
+
+    def on_event(event, seconds, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        _, history = launcher.main(
+            ["--arch", "resnet50", "--reduced", "--batch", "8", "--steps",
+             "3", "--devices", "1", "--log-every", "1", *extra])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert [h["step"] for h in history] == [0, 1, 2]
+    assert compiled.count("jit(train_step)") == 1, compiled
+    assert compiled.count("jit(<lambda>)") == 1, compiled
+
+
+def test_compile_cache_is_a_fixed_dir_in_the_checkout(monkeypatch):
+    """Off the CPU the launcher caches compiled programs in
+    ``<checkout>/.jax_cache``, unless ``JAX_COMPILATION_CACHE_DIR`` names
+    a directory; on the CPU it caches nothing."""
+    from repro.launch import compile_cache as cc
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(cc.CACHE_DIR) == os.path.join(checkout, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        cc.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/set/by/the/host")
+        cc.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        cc.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(cc.CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_loss_decreases_lm():
@@ -98,7 +147,7 @@ def test_lcg_stream_is_learnable_structure():
 def test_eval_step_runs():
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s = st.init_state(model, 0)
     from repro.data.synthetic import prototype_imagenet
     batch = prototype_imagenet(cfg, batch=8, step=jnp.int32(0))
@@ -111,6 +160,7 @@ DDP_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.shapes import InputShape
 from repro.models.registry import build_model
@@ -120,7 +170,7 @@ from repro.core import lars
 from repro.core.schedule import ScheduleConfig, make_schedule
 from repro.data.synthetic import make_batch_fn
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = get_config("resnet50").reduced()
 model = build_model(cfg)
 sched = make_schedule(ScheduleConfig(base_lr=0.2, warmup_steps=1,
@@ -128,7 +178,7 @@ sched = make_schedule(ScheduleConfig(base_lr=0.2, warmup_steps=1,
 bf = make_batch_fn(cfg, InputShape("t", "train", 0, 16), mesh=mesh)
 res = {}
 for comm in ("naive", "bucketed"):
-    s = st.init_state(model, 0)
+    s = st.init_state(model, 0, mesh)
     step = jax.jit(make_train_step(model, lars.OptConfig(kind="lars"),
                                    sched, mesh=mesh, comm=comm,
                                    bucket_mb=0.25))
@@ -171,7 +221,7 @@ jax.tree.map(lambda x, y: np.testing.assert_allclose(
 from repro.configs.base import CommConfig
 res = {}
 for strat in ("psum", "ring"):
-    s = st.init_state(model, 0)
+    s = st.init_state(model, 0, mesh)
     cc = CommConfig(strategy=strat, bucket_mb=0.25, wire_dtype="f32")
     step = jax.jit(make_train_step(model, lars.OptConfig(kind="lars"),
                                    sched, mesh=mesh, comm=cc))
@@ -211,7 +261,7 @@ def test_grad_accum_matches_full_batch():
     """grad_accum=N over the same examples == one full-batch step."""
     cfg = get_config("qwen1.5-0.5b").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.1, warmup_steps=1,
                                          total_steps=10))
     bf = make_batch_fn(cfg, InputShape("t", "train", 32, 8), mesh=mesh)
@@ -249,7 +299,7 @@ def _train_sharded(comm_cfg, steps=3, seed=0):
     (train_step, jitted fn, final state, losses)."""
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.5, warmup_steps=1,
                                          total_steps=10))
     step = make_train_step(model, lars.OptConfig(kind="lars"), sched,
@@ -258,7 +308,7 @@ def _train_sharded(comm_cfg, steps=3, seed=0):
     f = jax.jit(step)
     bf = make_batch_fn(cfg, InputShape("t", "train", 0, 8), mesh=mesh,
                        seed=seed)
-    s = st.init_state(model, seed, sharded_plan=step.bucket_plan,
+    s = st.init_state(model, seed, mesh, sharded_plan=step.bucket_plan,
                       n_shards=step.n_shards)
     losses = []
     for _ in range(steps):
@@ -329,7 +379,7 @@ def test_checkpoint_roundtrip_sharded(tmp_path):
     # resume one step (same jitted fn => same executable) and compare to
     # the uninterrupted third step
     bf = make_batch_fn(cfg, InputShape("t", "train", 0, 8),
-                       mesh=jax.make_mesh((1, 1), ("data", "model")))
+                       mesh=make_mesh((1, 1), ("data", "model")))
     s3, m3 = f(s2, bf(s2.step))
     r3, mr3 = f(restored, bf(restored.step))
     assert float(m3["loss"]) == float(mr3["loss"])
@@ -348,7 +398,7 @@ def test_checkpoint_rejects_shard_mismatch(tmp_path):
     ckpt.save(s, str(tmp_path))
     cfg = get_config("resnet50").reduced()
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sched = make_schedule(ScheduleConfig(base_lr=0.5, warmup_steps=1,
                                          total_steps=4))
     step = make_train_step(model, lars.OptConfig(kind="lars"), sched,
