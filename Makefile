@@ -35,9 +35,7 @@ ci:              ## reproduce both .github/workflows/ci.yml jobs locally
 		assert any('guard.overhead' in r['name'] for r in rows), \
 		'guard sentinel-overhead smoke row missing from bench artifact'; \
 		assert any('guard.recovery' in r['name'] for r in rows), \
-		'guard recovery-ladder smoke row missing from bench artifact'; \
-		assert any('trace.drift' in r['name'] for r in rows), \
-		'trace-drift scoreboard row missing from bench artifact'"
+		'guard recovery-ladder smoke row missing from bench artifact'"
 
 test-tier1:      ## fast in-process subset (no 8-device subprocesses)
 	$(PY) -m pytest -x -q -m "tier1 and not tier2"

@@ -951,121 +951,6 @@ def bench_guard_recovery(quick: bool):
          f"{int(fin.step)} — replayed, not dropped")
 
 
-def bench_trace_drift(quick: bool):
-    """Predicted-vs-measured drift scoreboard rows (part of --smoke,
-    asserted in CI — docs/observability.md §Drift rows): one 8-device
-    subprocess runs traced bucket collectives (psum all-reduce; ring
-    reduce-scatter + all-gather, the ZeRO-1 span pair) and ships the
-    median measured span times back; the parent rebuilds the identical
-    CommPlan and scores them against the ``comm/cost.py`` prediction.
-    Host-CPU collectives vs v5e link constants means the absolute rel_err
-    is huge and meaningless — the row is a per-PR *trend* (the bench JSON
-    artifact) and an end-to-end assertion that every planned bucket span
-    is traced and scored."""
-    import json as json_mod
-    import subprocess
-    import sys
-
-    from repro.comm import plan as comm_plan_mod
-    from repro.configs.base import CommConfig
-    from repro.core import bucketing
-    from repro.obs import drift as obs_drift
-
-    t0 = time.perf_counter()
-    script = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json
-import jax
-from repro.launch.mesh import make_mesh
-from jax.sharding import PartitionSpec as P
-from repro.core import bucketing, ddp
-from repro.core.compat import shard_map
-from repro.obs import drift as obs_drift
-from repro.obs.trace import Tracer
-
-STEPS = 4
-mesh = make_mesh((8,), ("data",))
-ks = jax.random.split(jax.random.PRNGKey(0), 12)
-tree = {f"t{i}": jax.random.normal(ks[i], ((i % 5 + 1) * 128, 128))
-        for i in range(12)}
-plan = bucketing.make_plan(tree, bucket_mb=0.25)
-spec = jax.tree.map(lambda _: P(), tree)
-
-tr = Tracer()                                    # psum all-reduce (ar[bi])
-f = jax.jit(shard_map(
-    lambda t: ddp.allreduce_grads(t, strategy="psum", axes=("data",),
-                                  plan=plan, tracer=tr),
-    mesh=mesh, in_specs=(spec,), out_specs=spec))
-for s in range(STEPS):
-    tr.begin_step()
-    jax.block_until_ready(f(tree))
-    tr.end_step(s)
-print("psum;" + json.dumps(obs_drift.measured_span_times(tr)), flush=True)
-
-tr2 = Tracer()                # ring RS + AG (rs[bi]/ag[bi], ZeRO-1 pair)
-def rs_ag(t):
-    shards = ddp.reduce_scatter_grads(t, strategy="ring", axes=("data",),
-                                      plan=plan, tracer=tr2)
-    return ddp.all_gather_params(shards, plan, shard_axis="data",
-                                 tracer=tr2)
-f2 = jax.jit(shard_map(rs_ag, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec))
-for s in range(STEPS):
-    tr2.begin_step()
-    jax.block_until_ready(f2(tree))
-    tr2.end_step(s)
-print("ring;" + json.dumps(obs_drift.measured_span_times(tr2)), flush=True)
-"""
-    try:
-        r = subprocess.run([sys.executable, "-c", script],
-                           capture_output=True, text=True, timeout=600,
-                           env=_host_mesh_env())
-    except subprocess.TimeoutExpired:
-        emit("trace.drift", (time.perf_counter() - t0) * 1e6,
-             "FAILED: 600s subprocess timeout")
-        return
-    res = {}
-    for line in r.stdout.strip().splitlines():
-        if ";" in line:
-            name, payload = line.split(";", 1)
-            try:
-                res[name] = json_mod.loads(payload)
-            except ValueError:
-                pass
-    if not res:
-        emit("trace.drift", (time.perf_counter() - t0) * 1e6,
-             f"FAILED: {r.stderr[-200:]}")
-        return
-    # the child's plan, rebuilt from the same shapes (packing is static)
-    tree = {f"t{i}": jnp.zeros(((i % 5 + 1) * 128, 128))
-            for i in range(12)}
-    plan = bucketing.make_plan(tree, bucket_mb=0.25)
-    for sched, shard in (("psum", False), ("ring", True)):
-        if sched not in res:
-            emit(f"trace.drift_{sched}", (time.perf_counter() - t0) * 1e6,
-                 f"MISSING rows: {r.stderr[-120:]}")
-            continue
-        cc = CommConfig(strategy=sched, bucket_mb=0.25,
-                        sharding="zero1" if shard else "replicated")
-        cplan = comm_plan_mod.make(
-            cc, plan, resolved_bucket_mb=0.25, mesh_axes=("data",),
-            mesh_sizes=(8,), shard_axis="data",
-            n_shards=8 if shard else 1, strategy=sched, overlap=False,
-            sharding="zero1" if shard else "replicated", gather="at_end")
-        drifts = obs_drift.compute(res[sched], cplan)
-        want = plan.n_buckets * (2 if shard else 1)
-        assert len(drifts) == want, (
-            f"{sched}: scored {len(drifts)} spans, planned {want} "
-            f"({[d.name for d in drifts]})")
-        agg = obs_drift.aggregate(drifts)
-        kinds = "rs+ag" if shard else "ar"
-        emit(f"trace.drift_{sched}", (time.perf_counter() - t0) * 1e6,
-             f"{len(drifts)} {kinds} spans over {plan.n_buckets} buckets "
-             f"all traced+scored; hostCPU-vs-v5e aggregate rel_err "
-             f"{agg:+.1f} (trend row, not an accuracy claim)")
-
-
 def bench_autotune_plan(quick: bool):
     """Pure cost-model rows (no training): the autotuner's joint
     (schedule x bucket size) pick per production mesh — the plan
@@ -1094,18 +979,17 @@ ALL = [bench_table1, bench_fig2, bench_fig3, bench_fig4,
        bench_comm_schedules, bench_comm_overlap, bench_comm_shard_update,
        bench_autotune_plan, bench_shard_update_plan,
        bench_gather_ahead_plan, bench_zero3_plan, bench_ckpt_roundtrip,
-       bench_guard_overhead, bench_guard_recovery, bench_trace_drift]
+       bench_guard_overhead, bench_guard_recovery]
 
-# --smoke: the CI micro-run — pure-math projection/accounting rows plus ONE
-# small 8-device subprocess (bench_trace_drift: traced collectives, no
-# model training) and the in-process guard pair (one guarded reduced-ResNet
-# compile shared by both), finishes in a few minutes and emits the JSON
-# artifact that tracks the bench trajectory per-PR (including the
-# sharded-update, gather-ahead, drift, and guard rows)
+# --smoke: the CI micro-run — pure-math projection/accounting rows plus the
+# in-process guard pair (one guarded reduced-ResNet compile shared by
+# both), finishes in a few minutes and emits the JSON artifact that tracks
+# the bench trajectory per-PR (including the sharded-update, gather-ahead
+# and guard rows)
 SMOKE = [bench_table1, bench_fig2, bench_autotune_plan,
          bench_shard_update_plan, bench_gather_ahead_plan,
          bench_zero3_plan, bench_ckpt_roundtrip, bench_guard_overhead,
-         bench_guard_recovery, bench_trace_drift]
+         bench_guard_recovery]
 
 
 def main() -> None:

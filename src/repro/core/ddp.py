@@ -33,21 +33,19 @@ import jax.numpy as jnp
 from repro.core import bucketing
 from repro.core.compat import axes_size
 from repro.core.precision import grads_to_comm, grads_to_master
-from repro.obs import trace as obs_trace
 
 
 def allreduce_grads(grads, *, strategy: str, axes: Sequence[str],
                     plan: "bucketing.BucketPlan" = None,
                     comm_dtype=jnp.bfloat16, use_kernel: bool = False,
-                    interpret: bool = None, tracer=None):
+                    interpret: bool = None):
     """Reduce-mean gradients over the data-parallel mesh axes.
     Must be called inside shard_map. Returns fp32 gradients.
 
     ``comm_dtype`` is the wire dtype (paper §IV: bf16; f32 reproduces the
     full-precision baseline); ``use_kernel`` swaps the ring schedules' inner
-    fold for the Pallas ring-step kernel. ``tracer`` (``obs.trace.Tracer``)
-    plants one ``ar[bi]`` span probe per bucket — begin when the packed
-    buffer exists, end when the reduced buffer does."""
+    fold for the Pallas ring-step kernel. Each bucket's collective runs
+    under the named scope ``ar_b<k>``."""
     n = axes_size(axes)
 
     if strategy == "naive":
@@ -63,24 +61,20 @@ def allreduce_grads(grads, *, strategy: str, axes: Sequence[str],
     # order; payload is the paper's "several megabytes"
     out = []
     for b, buf in enumerate(bufs):
-        obs_trace.mark(tracer, f"ar[b{b}]", "B", [buf], bucket=b)
-        red = schedule(buf, tuple(axes), use_kernel=use_kernel,
-                       interpret=interpret)
-        obs_trace.mark(tracer, f"ar[b{b}]", "E", [red], bucket=b)
-        out.append(red)
+        with jax.named_scope(f"ar_b{b}"):
+            out.append(schedule(buf, tuple(axes), use_kernel=use_kernel,
+                                interpret=interpret))
     red = bucketing.unpack(out, plan, dtype=jnp.float32)
     return jax.tree.map(lambda g: g / n, red)
 
 
 def _overlap_bucket_fn(gi, slots, schedule, axes, comm_dtype, use_kernel,
-                       interpret, tracer=None):
+                       interpret):
     """custom_vjp identity over one bucket group's param leaves whose
     backward rule packs the group's cotangents, runs the collective, and
     returns the reduced-mean fp32 gradients — so the collective sits inside
-    the backward graph, data-dependent only on this group's grads. With a
-    ``tracer``, the group-boundary hook doubles as the ``ar[b<gi>]`` span:
-    begin on the cotangents (grads ready = collective issue), end on the
-    reduced buffer."""
+    the backward graph, data-dependent only on this group's grads. The
+    packing and the collective run under the named scope ``ar_b<gi>``."""
     @jax.custom_vjp
     def bucket_identity(leaves):
         return leaves
@@ -89,10 +83,10 @@ def _overlap_bucket_fn(gi, slots, schedule, axes, comm_dtype, use_kernel,
         return leaves, None
 
     def bwd(_, gs):
-        obs_trace.mark(tracer, f"ar[b{gi}]", "B", gs, bucket=gi)
-        buf = bucketing.pack_group(gs, slots, dtype=comm_dtype)
-        buf = schedule(buf, axes, use_kernel=use_kernel, interpret=interpret)
-        obs_trace.mark(tracer, f"ar[b{gi}]", "E", [buf], bucket=gi)
+        with jax.named_scope(f"ar_b{gi}"):
+            buf = bucketing.pack_group(gs, slots, dtype=comm_dtype)
+            buf = schedule(buf, axes, use_kernel=use_kernel,
+                           interpret=interpret)
         n = axes_size(axes)
         pieces = bucketing.unpack_group(buf, slots, dtype=jnp.float32)
         outs = []
@@ -146,7 +140,7 @@ def _wrap_param_groups(params, plan: "bucketing.BucketPlan", make_group_fn,
 
 
 def _shard_bucket_fn(gi, slots, finals, rs, axes, comm_dtype, use_kernel,
-                     interpret, tracer=None):
+                     interpret):
     """custom_vjp identity over one bucket group's ``(leaves, sink)`` whose
     backward rule packs the group's cotangents, runs the schedule's
     REDUCE-SCATTER-terminal form, and emits the reduced-mean fp32 local
@@ -158,9 +152,8 @@ def _shard_bucket_fn(gi, slots, finals, rs, axes, comm_dtype, use_kernel,
     and every group after this one in the chain still needs the raw local
     gradient to pack its own span — so only the group holding the tensor's
     FINAL span (``finals[j]``, the last identity to fire) zeroes the leaf
-    cotangent; the others pass it through untouched. With a ``tracer``,
-    the sink fire is the ``rs[b<gi>]`` span: begin on the cotangents, end
-    on the reduced shard."""
+    cotangent; the others pass it through untouched. The packing, the
+    reduce-scatter and the mean run under the named scope ``rs_b<gi>``."""
     @jax.custom_vjp
     def bucket_identity(leaves, sink):
         del sink
@@ -171,12 +164,10 @@ def _shard_bucket_fn(gi, slots, finals, rs, axes, comm_dtype, use_kernel,
         return leaves, None
 
     def bwd(_, gs):
-        obs_trace.mark(tracer, f"rs[b{gi}]", "B", gs, bucket=gi)
-        buf = bucketing.pack_group(gs, slots, dtype=comm_dtype)
-        shard = rs(buf, axes, use_kernel=use_kernel, interpret=interpret)
-        n = axes_size(axes)
-        shard = grads_to_master(shard) / n
-        obs_trace.mark(tracer, f"rs[b{gi}]", "E", [shard], bucket=gi)
+        with jax.named_scope(f"rs_b{gi}"):
+            buf = bucketing.pack_group(gs, slots, dtype=comm_dtype)
+            shard = rs(buf, axes, use_kernel=use_kernel, interpret=interpret)
+            shard = grads_to_master(shard) / axes_size(axes)
         outs = tuple(jnp.zeros(g.shape, g.dtype) if fin else g
                      for g, fin in zip(gs, finals))
         return (outs, shard)
@@ -198,8 +189,7 @@ def make_shard_sinks(plan: "bucketing.BucketPlan", n_shards: int):
 def wrap_params_for_overlap(params, plan: "bucketing.BucketPlan", *,
                             strategy: str, axes: Sequence[str],
                             comm_dtype=jnp.bfloat16, use_kernel: bool = False,
-                            interpret: bool = None, shard_sinks=None,
-                            tracer=None):
+                            interpret: bool = None, shard_sinks=None):
     """Overlap-aware bucket scheduling (paper §III-C.2).
 
     Rebuilds ``params`` with each bucket group's leaves routed through an
@@ -230,8 +220,7 @@ def wrap_params_for_overlap(params, plan: "bucketing.BucketPlan", *,
         def shard_fn(gi, group):
             finals = tuple(final_map[id(s)] for s in group)
             return _shard_bucket_fn(gi, group, finals, rs, tuple(axes),
-                                    comm_dtype, use_kernel, interpret,
-                                    tracer)
+                                    comm_dtype, use_kernel, interpret)
 
         return _wrap_param_groups(params, plan, shard_fn,
                                   extras=shard_sinks)
@@ -241,7 +230,7 @@ def wrap_params_for_overlap(params, plan: "bucketing.BucketPlan", *,
         params, plan,
         lambda gi, group: _overlap_bucket_fn(gi, group, schedule,
                                              tuple(axes), comm_dtype,
-                                             use_kernel, interpret, tracer))
+                                             use_kernel, interpret))
 
 
 # --------------------------------------------------------------------------
@@ -250,7 +239,7 @@ def wrap_params_for_overlap(params, plan: "bucketing.BucketPlan", *,
 def reduce_scatter_grads(grads, *, strategy: str, axes: Sequence[str],
                          plan: "bucketing.BucketPlan",
                          comm_dtype=jnp.bfloat16, use_kernel: bool = False,
-                         interpret: bool = None, tracer=None):
+                         interpret: bool = None):
     """POST-backward scatter (the ``CommConfig.overlap=False`` sharded
     path; with overlap on, ``wrap_params_for_overlap(shard_sinks=...)``
     issues the same reduce-scatters from inside the backward instead):
@@ -258,46 +247,42 @@ def reduce_scatter_grads(grads, *, strategy: str, axes: Sequence[str],
     at the reduce-scatter. Returns one fp32 reduced-MEAN shard per bucket
     — this device's contiguous CHUNK-aligned 1/n slice
     (``comm.primitives.shard_index`` layout), already reduced over every
-    non-shard axis. Must be called inside shard_map."""
+    non-shard axis. Each bucket's scatter runs under the named scope
+    ``rs_b<k>``. Must be called inside shard_map."""
     from repro.comm import get_reduce_scatter
     rs = get_reduce_scatter(strategy)
     n = axes_size(axes)
     bufs = bucketing.pack(grads, plan, dtype=comm_dtype)
     shards = []
     for b, buf in enumerate(bufs):
-        obs_trace.mark(tracer, f"rs[b{b}]", "B", [buf], bucket=b)
-        shard = grads_to_master(rs(buf, tuple(axes), use_kernel=use_kernel,
-                                   interpret=interpret)) / n
-        obs_trace.mark(tracer, f"rs[b{b}]", "E", [shard], bucket=b)
-        shards.append(shard)
+        with jax.named_scope(f"rs_b{b}"):
+            shards.append(grads_to_master(
+                rs(buf, tuple(axes), use_kernel=use_kernel,
+                   interpret=interpret)) / n)
     return shards
 
 
 def all_gather_params(param_shards, plan: "bucketing.BucketPlan", *,
-                      shard_axis: str, wire_dtype=jnp.bfloat16,
-                      tracer=None):
+                      shard_axis: str, wire_dtype=jnp.bfloat16):
     """Gather phase: cast each fp32 master shard to the wire dtype once
     (bf16 by default — half the bytes of the fp32 grad all-gather the
     replicated path pays), ring all-gather along the shard axis, and unpack
     into the full param pytree. One independent collective per bucket, so
     a latency-hiding scheduler can slide each gather under surrounding
-    compute. Must be called inside shard_map. ``tracer`` plants the
-    ``ag[bi]`` span per bucket: begin at the gather issue (wire copy
-    ready), end when the gathered buffer exists."""
+    compute. Must be called inside shard_map. Each bucket's gather runs
+    under the named scope ``ag_b<k>``."""
     from repro.comm import primitives as prim
     bufs = []
     for b, shard in enumerate(param_shards):
         wire = grads_to_comm(shard, dtype=wire_dtype)
-        obs_trace.mark(tracer, f"ag[b{b}]", "B", [wire], bucket=b)
-        buf = prim.ring_all_gather(wire, shard_axis, plan.bucket_sizes[b])
-        obs_trace.mark(tracer, f"ag[b{b}]", "E", [buf], bucket=b)
-        bufs.append(buf)
+        with jax.named_scope(f"ag_b{b}"):
+            bufs.append(prim.ring_all_gather(wire, shard_axis,
+                                             plan.bucket_sizes[b]))
     return bucketing.unpack(bufs, plan, dtype=jnp.float32)
 
 
 def gather_ahead_params(shards, plan: "bucketing.BucketPlan", *,
-                        shard_axis: str, wire_dtype=jnp.bfloat16,
-                        tracer=None):
+                        shard_axis: str, wire_dtype=jnp.bfloat16):
     """Gather-AHEAD: rebuild this step's forward params from the persistent
     master shards (``train.state.TrainState.shards``, updated by the
     previous step) at the START of the step. Each bucket's all-gather is an
@@ -312,15 +297,14 @@ def gather_ahead_params(shards, plan: "bucketing.BucketPlan", *,
     point (step start, from the persistent shards) differs. Must be called
     inside shard_map with the shards' local view."""
     return all_gather_params(shards, plan, shard_axis=shard_axis,
-                             wire_dtype=wire_dtype, tracer=tracer)
+                             wire_dtype=wire_dtype)
 
 
 # --------------------------------------------------------------------------
 # ZeRO-3 just-in-time gather (CommConfig.sharding='zero3'; docs/comm.md)
 
 def jit_gather_params(shards, plan: "bucketing.BucketPlan", *,
-                      shard_axis: str, wire_dtype=jnp.bfloat16,
-                      tracer=None):
+                      shard_axis: str, wire_dtype=jnp.bfloat16):
     """ZeRO-3 gather: rebuild the forward params from the persistent master
     shards with per-GROUP lifetimes — called *inside* the differentiated
     function, so no full replica ever lives in ``TrainState``.
@@ -335,17 +319,16 @@ def jit_gather_params(shards, plan: "bucketing.BucketPlan", *,
     layers as consumers, so the latency-hiding scheduler streams gather
     ``g`` under the forward compute of the groups already gathered (the
     forward walks groups in REVERSE packing order: bucket 0 holds the last
-    layers). ``tracer`` plants ``ag[g<gi>]`` spans — a distinct name from
-    the ZeRO-1 ``ag[b<gi>]`` step-boundary gathers so drift rows can tell
-    the timelines apart. Must be called inside shard_map with the shards'
-    local view."""
+    layers). Each group's gather runs under the named scope ``ag_g<gi>``, a
+    distinct name from the ZeRO-1 ``ag_b<k>`` step-boundary gathers. Must
+    be called inside shard_map with the shards' local view."""
     from repro.comm import primitives as prim
     vals_slot_order = []
     for gi, group in enumerate(plan.groups):
         wire = grads_to_comm(shards[gi], dtype=wire_dtype)
-        obs_trace.mark(tracer, f"ag[g{gi}]", "B", [wire], bucket=gi)
-        buf = prim.ring_all_gather(wire, shard_axis, plan.bucket_sizes[gi])
-        obs_trace.mark(tracer, f"ag[g{gi}]", "E", [buf], bucket=gi)
+        with jax.named_scope(f"ag_g{gi}"):
+            buf = prim.ring_all_gather(wire, shard_axis,
+                                       plan.bucket_sizes[gi])
         vals_slot_order.extend(
             bucketing.unpack_group(buf, group, dtype=jnp.float32))
     # groups concatenate back to plan.slots order (buckets are assigned in

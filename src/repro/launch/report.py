@@ -238,39 +238,6 @@ def shard_update_section(arch: str = "resnet50") -> str:
     return "\n".join(rows)
 
 
-def trace_section(trace_json: str) -> str:
-    """Per-step / per-bucket span table from a ``launch.train --trace``
-    Chrome-trace export (docs/observability.md): one row per (step, span),
-    compute rows first, then the bucket comm spans in bucket order — the
-    human-readable twin of the chrome://tracing view."""
-    from repro.obs import trace as obs_trace
-
-    spans = obs_trace.spans_from_chrome(obs_trace.load_chrome(trace_json))
-    steps = sorted({s.step for s in spans if s.step >= 0})
-    rows = [f"### Step timeline ({os.path.basename(trace_json)}: "
-            f"{len(steps)} steps, {len(spans)} spans)\n",
-            "| step | span | cat | start (into step) | duration |",
-            "|---|---|---|---|---|"]
-    for st in steps:
-        in_step = [s for s in spans if s.step == st]
-        t_start = min((s.t0 for s in in_step if s.name == "step"),
-                      default=min(s.t0 for s in in_step))
-        order = {"step": 0, "compute": 1, "comm": 2, "host": 3}
-        for s in sorted(in_step,
-                        key=lambda s: (order.get(s.cat, 9), s.t0, s.name)):
-            rows.append(f"| {st} | {s.name} | {s.cat} "
-                        f"| {fmt_t(max(s.t0 - t_start, 0.0))} "
-                        f"| {fmt_t(s.dur_s)} |")
-    host = [s for s in spans if s.step < 0]
-    if host:
-        rows.append("\n### Host events (outside step windows)\n")
-        rows.append("| span | cat | duration |")
-        rows.append("|---|---|---|")
-        for s in sorted(host, key=lambda s: s.t0):
-            rows.append(f"| {s.name} | {s.cat} | {fmt_t(s.dur_s)} |")
-    return "\n".join(rows)
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", default="experiments/dryrun/baseline")
@@ -278,10 +245,7 @@ def main():
                     help="second records dir: emit baseline-vs-optimized")
     ap.add_argument("--section", default="roofline",
                     choices=["roofline", "dryrun", "comm", "autotune",
-                             "shard", "trace"])
-    ap.add_argument("--trace-json", default="trace.json",
-                    help="--section trace input: the Chrome-trace JSON "
-                         "written by launch.train --trace")
+                             "shard"])
     args = ap.parse_args()
     if args.section == "comm":
         print(comm_section())
@@ -291,9 +255,6 @@ def main():
         return
     if args.section == "shard":
         print(shard_update_section())
-        return
-    if args.section == "trace":
-        print(trace_section(args.trace_json))
         return
     recs = load(args.dir)
     if args.compare:

@@ -7,15 +7,18 @@ Examples:
       --batch 32 --steps 200 --comm bucketed --warmup 20
 
 Observability (docs/observability.md): ``--metrics out.jsonl`` mirrors the
-tag stream to a JSONL artifact; ``--trace out.json`` attaches a step-
-timeline tracer to the explicit-DDP paths and writes a Chrome-trace JSON
-(chrome://tracing / Perfetto) at exit, plus ``obs.drift.*`` rows scoring
-the traced bucket comm spans against the CommPlan's predicted timeline.
+tag stream to a JSONL artifact; ``--trace DIR`` runs the training loop
+under the JAX profiler and writes its profile (``.xplane.pb`` plus a
+Perfetto ``perfetto_trace.json.gz``) under ``DIR``: the device ops named by
+the step's scopes and the loop's host spans, on one clock.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import glob
 import json
+import os
 
 import jax
 
@@ -29,7 +32,6 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.registry import build_model
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 from repro.train import loop
 from repro.train.state import init_state
 from repro.train.step import make_eval_step, make_train_step
@@ -147,11 +149,10 @@ def main(argv=None):
                     help="log (and keep in the history) every K-th step's "
                          "metrics, and the last step's")
     ap.add_argument("--history-out", default=None)
-    ap.add_argument("--trace", default=None, metavar="OUT.json",
-                    help="attach the step-timeline tracer and write a "
-                         "Chrome-trace JSON (chrome://tracing / Perfetto) "
-                         "at exit; also scores traced bucket comm spans "
-                         "against the CommPlan prediction (obs.drift.*)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="run the training loop under the JAX profiler and "
+                         "write its profile (.xplane.pb and a Perfetto "
+                         "trace) under DIR")
     ap.add_argument("--metrics", default=None, metavar="OUT.jsonl",
                     help="mirror every metrics event (the MLPerf tag "
                          "stream + obs.* rows) to a JSONL file")
@@ -161,17 +162,15 @@ def main(argv=None):
     reg = obs_metrics.default_registry()
     sink = (reg.add_sink(obs_metrics.JsonlSink(args.metrics))
             if args.metrics else None)
-    tracer = obs_trace.Tracer() if args.trace else None
     try:
-        return _run(args, reg=reg, tracer=tracer)
+        return _run(args, reg=reg)
     finally:
         if sink is not None:
             reg.remove_sink(sink)
             sink.close()
 
 
-def _run(args, *, reg: obs_metrics.Registry,
-         tracer: "obs_trace.Tracer | None"):
+def _run(args, *, reg: obs_metrics.Registry):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -275,7 +274,7 @@ def _run(args, *, reg: obs_metrics.Registry,
                                  profile_batch=(batch_fn(0) if
                                                 args.backward_profile ==
                                                 "measured" else None),
-                                 tracer=tracer, guard=args.guard)
+                                 guard=args.guard)
     if getattr(train_step, "tuned", None) is not None:
         t = train_step.tuned
         reg.event("autotune_plan",
@@ -318,33 +317,25 @@ def _run(args, *, reg: obs_metrics.Registry,
         reg.event("elastic_resume",
                   f"elastic resume: restored step {int(state.step)}, "
                   f"resharded {old_n} -> {new_n} shards", where=WHERE)
-    state, history = loop.train(
-        state, train_step, batch_fn, steps=args.steps, eval_step=eval_step,
-        eval_batch_fn=batch_fn, eval_every=args.eval_every,
-        log_every=args.log_every, ckpt_dir=args.ckpt_dir,
-        ckpt_every=args.ckpt_every, seed=args.seed,
-        keep_last_k=args.keep_last_k, step_timeout_s=args.step_timeout_s,
-        max_step_retries=args.max_step_retries,
-        comm_plan=getattr(train_step, "comm_plan", None),
-        faults=FaultInjector(fault_list),
-        tracer=tracer, guard=guard_cfg)
-    if tracer is not None:
-        path = obs_trace.export_chrome(tracer, args.trace)
-        reg.event("trace_written",
-                  {"path": path, "steps": len(tracer.steps),
-                   "spans": len(tracer.spans())}, where=WHERE)
-        comm_plan = getattr(train_step, "comm_plan", None)
-        if comm_plan is not None:
-            from repro.obs import drift as obs_drift
-            drifts = obs_drift.compute(tracer, comm_plan)
-            if drifts:
-                obs_drift.emit(drifts, comm_plan, registry=reg)
-            else:
-                reg.event("obs.drift.no_spans",
-                          {"schedule": comm_plan.schedule,
-                           "note": "no traced bucket comm spans to score "
-                                   "(xla path, or zero completed steps)"},
-                          where=WHERE)
+    profile = (jax.profiler.trace(args.trace, create_perfetto_trace=True)
+               if args.trace else contextlib.nullcontext())
+    with profile:
+        state, history = loop.train(
+            state, train_step, batch_fn, steps=args.steps,
+            eval_step=eval_step, eval_batch_fn=batch_fn,
+            eval_every=args.eval_every, log_every=args.log_every,
+            ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
+            seed=args.seed, keep_last_k=args.keep_last_k,
+            step_timeout_s=args.step_timeout_s,
+            max_step_retries=args.max_step_retries,
+            comm_plan=getattr(train_step, "comm_plan", None),
+            faults=FaultInjector(fault_list), guard=guard_cfg)
+    if args.trace:
+        found = sorted(glob.glob(os.path.join(args.trace, "**",
+                                              "*.xplane.pb"), recursive=True),
+                       key=os.path.getmtime)
+        reg.event("trace_written", {"path": found[-1] if found else None,
+                                    "dir": args.trace}, where=WHERE)
     if args.history_out:
         with open(args.history_out, "w") as f:
             json.dump(history, f, indent=1)

@@ -3,13 +3,12 @@
 * ``obs.metrics`` — typed counter/gauge/event registry with pluggable
   sinks (stdout in the MLPerf-v0.5.0 tag format, JSONL file, in-memory);
   the structured replacement for the loop's ad-hoc ``print`` logging.
-* ``obs.trace``   — host-timestamped step-timeline tracer: per-bucket
-  comm spans planted via ``jax.debug.callback`` probes at the ddp hooks,
-  Chrome-trace (chrome://tracing / Perfetto) JSON export.
-* ``obs.drift``   — predicted-vs-measured drift monitor: traced bucket
-  spans scored against the CommPlan's ``comm/cost.py`` timeline, emitted
-  as ``obs.drift.*`` metric rows and the ``trace.drift_*`` CI bench rows.
+
+Timing lives in the JAX profiler's own trace: the train step names its
+phases with ``jax.named_scope`` (``forward``, ``update``, each bucket's
+``ar_b<k>``/``rs_b<k>``/``ag_b<k>``/``ag_g<k>``) and the training loop
+wraps each step in ``jax.profiler`` host annotations (``train_step``,
+``loop.*``); ``launch.train --trace DIR`` records both.
 """
 from repro.obs.metrics import (JsonlSink, MemorySink, Registry,  # noqa: F401
                                StdoutSink, default_registry)
-from repro.obs.trace import Span, Tracer  # noqa: F401

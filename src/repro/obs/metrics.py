@@ -22,7 +22,7 @@ event      a tagged occurrence (``run_start``, ``train_step``, ...) with
            an optional structured value — the MLPerf tag stream.
 counter    monotonically accumulating count; the emitted value is the
            running total (``obs.retry_total`` etc.).
-gauge      a point-in-time measurement (``obs.drift.<schedule>.rel_err``).
+gauge      a point-in-time measurement (``obs.guard.gnorm``).
 =========  ==============================================================
 
 The module-level :func:`default_registry` carries a single

@@ -30,8 +30,7 @@ class:
 
 The guard is opt-in per run (``make_train_step(..., guard=True)`` +
 ``loop.train(..., guard=GuardConfig(...))``); with it off the trained
-graph is byte-identical to the unguarded one — same contract as the
-tracer's ``mark`` no-ops.
+graph is byte-identical to the unguarded one.
 """
 from __future__ import annotations
 

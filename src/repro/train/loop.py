@@ -32,13 +32,13 @@ timing stable under the watchdog.
 """
 from __future__ import annotations
 
-import contextlib
 import signal
 import threading
 import time
 from typing import Callable, Optional
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.obs import metrics as obs_metrics
 from repro.train import checkpoint as ckpt
@@ -131,7 +131,7 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
           ckpt_every: int = 0, seed: int = 0, keep_last_k: int = 0,
           step_timeout_s: float = 0.0, max_step_retries: int = 3,
           retry_backoff_s: float = 0.5, comm_plan=None, faults=None,
-          tracer=None, guard: Optional[GuardConfig] = None):
+          guard: Optional[GuardConfig] = None):
     """Runs optimizer steps up to global step ``steps`` (a resumed state
     continues from ``state.step``). Returns (state, history).
 
@@ -140,12 +140,16 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
     (``make_train_step(..., guard=True)``). A guarded step with
     ``guard=None`` runs under the default ``GuardConfig()``.
 
-    ``tracer`` (an ``obs.trace.Tracer``, also threaded into the step via
-    ``make_train_step(..., tracer=...)``) makes the loop own the step
-    windows: ``begin_step`` before dispatch, ``end_step`` after
-    ``block_until_ready`` (draining the async probe callbacks), plus host
-    spans for checkpoint commits and instants for watchdog/preemption
-    events. A watchdog-aborted step's window is discarded."""
+    Each step runs inside a ``jax.profiler.StepTraceAnnotation``
+    (``train_step``, with its step number) holding the host spans
+    ``loop.batch`` (the batch function), ``loop.release`` (dropping the
+    previous step's input state), ``loop.dispatch`` (the jitted call
+    until it returns), ``loop.wait`` (``block_until_ready``),
+    ``loop.readback`` (every device-to-host read of the step's metrics and
+    the log line built from them), ``loop.checkpoint`` and ``loop.eval``.
+    They cost well under a microsecond each unless a profiler is running,
+    and then land on its host plane, on the device trace's clock
+    (docs/observability.md)."""
     mlperf_log("run_start")
     mlperf_log("run_set_random_seed", seed)
     injector = (faults if isinstance(faults, FaultInjector)
@@ -182,9 +186,7 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
     def save_ckpt(s: TrainState) -> None:
         nonlocal last_saved_step
         gstep = int(s.step)
-        span = (tracer.host_span("checkpoint_commit", step=gstep)
-                if tracer is not None else contextlib.nullcontext())
-        with span:
+        with TraceAnnotation("loop.checkpoint"):
             path = ckpt.save(s, ckpt_dir, tag=ckpt.step_tag(gstep),
                              comm_plan=comm_plan, keep_last_k=keep_last_k)
         last_saved_step = gstep
@@ -217,198 +219,203 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
         ring.snapshot(state)
     try:
         while i < steps:
-            batch = injector.poison_batch(batch_fn(state.step), i)
-            guard_in = None
-            if guarded:
-                import numpy as np
-                scale = (1.0 if rewarm_start is None
-                         else rewarm(i - rewarm_start))
-                guard_in = {"lr_scale": np.float32(scale),
-                            "loss_scale": np.float32(injector.loss_scale(i))}
+            with StepTraceAnnotation("train_step", step_num=i):
+                with TraceAnnotation("loop.batch"):
+                    batch = injector.poison_batch(batch_fn(state.step), i)
+                with TraceAnnotation("loop.release"):
+                    # the previous step's closure holds that step's input
+                    # state: dropping it destroys every array handle of it,
+                    # host time in which the device idles
+                    run_step = None
+                guard_in = None
+                if guarded:
+                    import numpy as np
+                    scale = (1.0 if rewarm_start is None
+                             else rewarm(i - rewarm_start))
+                    guard_in = {
+                        "lr_scale": np.float32(scale),
+                        "loss_scale": np.float32(injector.loss_scale(i))}
 
-            def run_step(state=state, batch=batch, i=i, guard_in=guard_in):
-                injector.on_step(i)
-                if tracer is not None:
-                    tracer.begin_step()
-                s2, m = (step_fn(state, batch, guard_in) if guarded
-                         else step_fn(state, batch))
-                out = jax.block_until_ready((s2, m))
-                if tracer is not None:
-                    tracer.end_step(i)
-                return out
+                def run_step(state=state, batch=batch, i=i,
+                             guard_in=guard_in):
+                    injector.on_step(i)
+                    with TraceAnnotation("loop.dispatch"):
+                        out = (step_fn(state, batch, guard_in) if guarded
+                               else step_fn(state, batch))
+                    with TraceAnnotation("loop.wait"):
+                        return jax.block_until_ready(out)
 
-            try:
-                state, metrics = _call_with_timeout(run_step, step_timeout_s)
-                retries = 0
-            except StepTimeoutError as e:
-                retries += 1
-                if tracer is not None:
-                    # the hung step's probes are meaningless (and may still
-                    # trickle in) — drop its window, mark the event
-                    tracer.abort_step()
-                    tracer.instant("watchdog_timeout", step=i,
-                                   attempt=retries)
-                obs_metrics.counter("obs.watchdog_timeout_total",
-                                    where="repro/train/loop.py", step=i)
-                mlperf_log("watchdog_timeout",
-                           {"step": i, "attempt": retries,
-                            "timeout_s": step_timeout_s})
-                history.append({"step": i, "watchdog_timeout": retries})
-                if retries > max_step_retries:
-                    raise RuntimeError(
-                        f"step {i} timed out {retries} times "
-                        f"(budget {step_timeout_s:.1f}s each) — giving up "
-                        f"after bounded retries") from e
-                if ckpt_dir:
-                    try:
-                        state = ckpt.load(state, ckpt_dir, tag=None)
-                        i = int(state.step)
-                        if tracer is not None:
-                            tracer.instant("watchdog_restore", step=i)
-                        mlperf_log("watchdog_restore", {"resume_step": i})
-                        history.append({"step": i, "watchdog_restore": 1})
-                    except ckpt.CheckpointError as err:
-                        # used to be a bare print that bypassed the tag
-                        # stream; now a first-class event on every sink
-                        mlperf_log("watchdog_no_checkpoint",
-                                   {"step": i, "error": str(err),
-                                    "action": "retrying with the "
-                                              "in-memory state"})
-                time.sleep(min(retry_backoff_s * 2 ** (retries - 1), 30.0))
-                continue
-            if guarded:
-                # ---- recovery ladder (docs/elastic.md §Numerical faults)
-                g_loss = float(metrics["loss"])
-                g_gnorm = float(metrics["gnorm"])
-                reason = None
-                if float(metrics["skipped"]) > 0:
-                    # rung 1: the in-graph sentinel refused the update —
-                    # state (and state.step) are unchanged, replay step i
-                    skips += 1
-                    obs_metrics.counter("obs.guard.skip_total",
-                                        where=_WHERE, step=i)
-                    if tracer is not None:
-                        tracer.instant("guard_skip", step=i, attempt=skips)
-                    mlperf_log("guard_skip",
-                               {"step": i, "attempt": skips,
-                                "nonfinite": int(float(metrics["nonfinite"]))})
-                    history.append({"step": i, "guard_skip": skips})
-                    if skips <= gcfg.max_skips:
-                        if not preempted.is_set():
-                            continue
-                        reason = "preempted mid-skip"
+                try:
+                    state, metrics = _call_with_timeout(run_step,
+                                                        step_timeout_s)
+                    retries = 0
+                except StepTimeoutError as e:
+                    retries += 1
+                    obs_metrics.counter("obs.watchdog_timeout_total",
+                                        where="repro/train/loop.py", step=i)
+                    mlperf_log("watchdog_timeout",
+                               {"step": i, "attempt": retries,
+                                "timeout_s": step_timeout_s})
+                    history.append({"step": i, "watchdog_timeout": retries})
+                    if retries > max_step_retries:
+                        raise RuntimeError(
+                            f"step {i} timed out {retries} times "
+                            f"(budget {step_timeout_s:.1f}s each) — giving "
+                            f"up after bounded retries") from e
+                    if ckpt_dir:
+                        try:
+                            state = ckpt.load(state, ckpt_dir, tag=None)
+                            i = int(state.step)
+                            mlperf_log("watchdog_restore",
+                                       {"resume_step": i})
+                            history.append({"step": i,
+                                            "watchdog_restore": 1})
+                        except ckpt.CheckpointError as err:
+                            # used to be a bare print that bypassed the tag
+                            # stream; now a first-class event on every sink
+                            mlperf_log("watchdog_no_checkpoint",
+                                       {"step": i, "error": str(err),
+                                        "action": "retrying with the "
+                                                  "in-memory state"})
+                    time.sleep(min(retry_backoff_s * 2 ** (retries - 1),
+                                   30.0))
+                    continue
+                if guarded:
+                    # recovery ladder (docs/elastic.md §Numerical faults)
+                    with TraceAnnotation("loop.readback"):
+                        g_loss = float(metrics["loss"])
+                        g_gnorm = float(metrics["gnorm"])
+                        g_skipped = float(metrics["skipped"])
+                    reason = None
+                    if g_skipped > 0:
+                        # rung 1: the in-graph sentinel refused the update —
+                        # state (and state.step) are unchanged, replay step i
+                        skips += 1
+                        obs_metrics.counter("obs.guard.skip_total",
+                                            where=_WHERE, step=i)
+                        with TraceAnnotation("loop.readback"):
+                            nonfinite = int(float(metrics["nonfinite"]))
+                        mlperf_log("guard_skip",
+                                   {"step": i, "attempt": skips,
+                                    "nonfinite": nonfinite})
+                        history.append({"step": i, "guard_skip": skips})
+                        if skips <= gcfg.max_skips:
+                            if not preempted.is_set():
+                                continue
+                            reason = "preempted mid-skip"
+                        else:
+                            reason = (f"{skips} consecutive nonfinite steps "
+                                      f"at step {i}")
                     else:
-                        reason = (f"{skips} consecutive nonfinite steps "
-                                  f"at step {i}")
-                else:
-                    skips = 0
-                    if detector.observe(g_loss, g_gnorm) != "ok":
-                        reason = (f"divergence at step {i}: loss "
-                                  f"{g_loss:.4g}, grad-norm {g_gnorm:.4g} "
-                                  f"vs EMA {detector.ema_gnorm or 0.0:.4g}")
-                if reason == "preempted mid-skip":
-                    # a skipped step committed nothing; drain like the
-                    # normal preemption path below
+                        skips = 0
+                        if detector.observe(g_loss, g_gnorm) != "ok":
+                            reason = (
+                                f"divergence at step {i}: loss "
+                                f"{g_loss:.4g}, grad-norm {g_gnorm:.4g} "
+                                f"vs EMA {detector.ema_gnorm or 0.0:.4g}")
+                    if reason == "preempted mid-skip":
+                        # a skipped step committed nothing; drain like the
+                        # normal preemption path below
+                        mlperf_log("preempt_drain", {"step": i})
+                        if ckpt_dir and last_saved_step != int(state.step):
+                            save_ckpt(state)
+                        break
+                    if reason is not None:
+                        recovered = False
+                        snap = ring.newest()
+                        if snap is not None and \
+                                rollbacks < gcfg.max_rollbacks:
+                            # rung 2: in-memory rollback, no checkpoint IO
+                            rollbacks += 1
+                            rstep, hstate = snap
+                            state = RollbackRing.restore(hstate)
+                            i = int(state.step)
+                            if gcfg.rewarmup_steps:
+                                rewarm_start = i
+                            obs_metrics.counter("obs.guard.rollback_total",
+                                                where=_WHERE, step=i)
+                            mlperf_log("guard_rollback",
+                                       {"resume_step": i, "used": rollbacks,
+                                        "reason": reason})
+                            history.append({"step": i,
+                                            "guard_rollback": rollbacks})
+                            if ckpt_dir:
+                                # guard-escalation save: step-tagged, so
+                                # keep_last_k retention can prune a spiky
+                                # run's trail (hand-named tags stay spared)
+                                save_ckpt(state)
+                            recovered = True
+                        elif ckpt_dir and restores < gcfg.max_restores:
+                            # rung 3: checkpoint restore
+                            try:
+                                state = ckpt.load(state, ckpt_dir, tag=None)
+                                restores += 1
+                                i = int(state.step)
+                                if gcfg.rewarmup_steps:
+                                    rewarm_start = i
+                                obs_metrics.counter(
+                                    "obs.guard.restore_total",
+                                    where=_WHERE, step=i)
+                                mlperf_log("guard_ckpt_restore",
+                                           {"resume_step": i,
+                                            "reason": reason})
+                                history.append({"step": i,
+                                                "guard_restore": 1})
+                                recovered = True
+                            except ckpt.CheckpointError as err:
+                                mlperf_log("guard_no_checkpoint",
+                                           {"step": i, "error": str(err)})
+                        if not recovered:
+                            # rung 4: bounded-retry exhaustion
+                            raise RuntimeError(
+                                f"numerical guard exhausted its recovery "
+                                f"ladder ({rollbacks} rollbacks, {restores} "
+                                f"checkpoint restores) — {reason}")
+                        skips = 0
+                        continue
+                    if ring is not None and int(state.step) % max(
+                            gcfg.snapshot_every, 1) == 0:
+                        # snapshot only a state that passed sentinel AND
+                        # detector: a spiked state is never a restore target
+                        ring.snapshot(state)
+                if log_every and (i % log_every == 0 or i == steps - 1):
+                    with TraceAnnotation("loop.readback"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                        history.append({"step": i, **m})
+                        mlperf_log("train_step",
+                                   {"step": i, "loss": round(m["loss"], 4),
+                                    "lr": round(m.get("lr", 0.0), 6)})
+                        if guarded:
+                            obs_metrics.gauge("obs.guard.gnorm", m["gnorm"],
+                                              where=_WHERE, step=i)
+                if eval_every and eval_fn is not None \
+                        and (i + 1) % eval_every == 0:
+                    with TraceAnnotation("loop.eval"):
+                        mlperf_log("eval_start")
+                        eb = eval_batch_fn(state.step + 100_000)
+                        ep = params_reader(state)
+                        em = {k: float(v) for k, v in
+                              eval_fn(ep, eb, state.bn_state).items()}
+                        mlperf_log("eval_accuracy",
+                                   {"step": i, **{k: round(v, 4)
+                                                  for k, v in em.items()}})
+                        mlperf_log("eval_stop")
+                        history.append({"step": i, **{f"eval_{k}": v
+                                                      for k, v in em.items()}})
+                i += 1
+                if ckpt_dir and ckpt_every and i % ckpt_every == 0:
+                    save_ckpt(state)
+                if preempted.is_set():
+                    # announced preemption: the in-flight step has drained
+                    # — commit the tail and hand back a resumable state.
+                    # Guarded by last_saved_step like the run-stop tail: a
+                    # drained step that also landed on the ckpt_every
+                    # cadence was saved two lines up and must not commit
+                    # the same step twice.
                     mlperf_log("preempt_drain", {"step": i})
                     if ckpt_dir and last_saved_step != int(state.step):
                         save_ckpt(state)
                     break
-                if reason is not None:
-                    recovered = False
-                    snap = ring.newest()
-                    if snap is not None and rollbacks < gcfg.max_rollbacks:
-                        # rung 2: in-memory rollback, no checkpoint IO
-                        rollbacks += 1
-                        rstep, hstate = snap
-                        state = RollbackRing.restore(hstate)
-                        i = int(state.step)
-                        if gcfg.rewarmup_steps:
-                            rewarm_start = i
-                        obs_metrics.counter("obs.guard.rollback_total",
-                                            where=_WHERE, step=i)
-                        if tracer is not None:
-                            tracer.instant("guard_rollback", step=i,
-                                           used=rollbacks)
-                        mlperf_log("guard_rollback",
-                                   {"resume_step": i, "used": rollbacks,
-                                    "reason": reason})
-                        history.append({"step": i,
-                                        "guard_rollback": rollbacks})
-                        if ckpt_dir:
-                            # guard-escalation save: step-tagged, so
-                            # keep_last_k retention can prune a spiky
-                            # run's trail (hand-named tags stay spared)
-                            save_ckpt(state)
-                        recovered = True
-                    elif ckpt_dir and restores < gcfg.max_restores:
-                        # rung 3: checkpoint restore
-                        try:
-                            state = ckpt.load(state, ckpt_dir, tag=None)
-                            restores += 1
-                            i = int(state.step)
-                            if gcfg.rewarmup_steps:
-                                rewarm_start = i
-                            obs_metrics.counter("obs.guard.restore_total",
-                                                where=_WHERE, step=i)
-                            if tracer is not None:
-                                tracer.instant("guard_ckpt_restore", step=i)
-                            mlperf_log("guard_ckpt_restore",
-                                       {"resume_step": i, "reason": reason})
-                            history.append({"step": i, "guard_restore": 1})
-                            recovered = True
-                        except ckpt.CheckpointError as err:
-                            mlperf_log("guard_no_checkpoint",
-                                       {"step": i, "error": str(err)})
-                    if not recovered:
-                        # rung 4: bounded-retry exhaustion
-                        raise RuntimeError(
-                            f"numerical guard exhausted its recovery "
-                            f"ladder ({rollbacks} rollbacks, {restores} "
-                            f"checkpoint restores) — {reason}")
-                    skips = 0
-                    continue
-                if ring is not None and \
-                        int(state.step) % max(gcfg.snapshot_every, 1) == 0:
-                    # snapshot only a state that passed sentinel AND
-                    # detector: a spiked state is never a restore target
-                    ring.snapshot(state)
-            if log_every and (i % log_every == 0 or i == steps - 1):
-                m = {k: float(v) for k, v in metrics.items()}
-                history.append({"step": i, **m})
-                mlperf_log("train_step",
-                           {"step": i, "loss": round(m["loss"], 4),
-                            "lr": round(m.get("lr", 0.0), 6)})
-                if guarded:
-                    obs_metrics.gauge("obs.guard.gnorm", m["gnorm"],
-                                      where=_WHERE, step=i)
-            if eval_every and eval_fn is not None \
-                    and (i + 1) % eval_every == 0:
-                mlperf_log("eval_start")
-                eb = eval_batch_fn(state.step + 100_000)
-                ep = params_reader(state)
-                em = {k: float(v)
-                      for k, v in eval_fn(ep, eb, state.bn_state).items()}
-                mlperf_log("eval_accuracy",
-                           {"step": i, **{k: round(v, 4)
-                                          for k, v in em.items()}})
-                mlperf_log("eval_stop")
-                history.append({"step": i, **{f"eval_{k}": v
-                                              for k, v in em.items()}})
-            i += 1
-            if ckpt_dir and ckpt_every and i % ckpt_every == 0:
-                save_ckpt(state)
-            if preempted.is_set():
-                # announced preemption: the in-flight step has drained —
-                # commit the tail and hand back a resumable state. Guarded
-                # by last_saved_step like the run-stop tail: a drained step
-                # that also landed on the ckpt_every cadence was saved two
-                # lines up and must not commit the same step twice.
-                if tracer is not None:
-                    tracer.instant("preempt_drain", step=i)
-                mlperf_log("preempt_drain", {"step": i})
-                if ckpt_dir and last_saved_step != int(state.step):
-                    save_ckpt(state)
-                break
         if ckpt_dir and last_saved_step != int(state.step):
             # run_stop tail: steps not a multiple of ckpt_every (or no
             # periodic cadence at all) must still leave a final checkpoint

@@ -38,7 +38,6 @@ from repro.core import bucketing, compat, ddp, lars
 from repro.core.label_smoothing import IGNORE, smoothed_xent, top1_accuracy
 from repro.core.precision import cast_to_compute
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 from repro.train import guard as guard_lib
 from repro.train.state import TrainState
 
@@ -59,14 +58,18 @@ def make_loss_fn(model, *, smoothing: float = 0.1, aux_coef: float = 0.01,
     cfg = model.cfg
 
     def loss_fn(params, batch, bn_state=None):
-        (logits, aux), new_bn = model.forward_train(params, batch, mesh,
-                                                    bn_state)
-        loss, n = _lm_loss(logits, batch["labels"], smoothing=smoothing)
-        total = loss + aux_coef * aux
-        acc = top1_accuracy(logits, batch["labels"]
-                            if logits.shape[:-1] == batch["labels"].shape
-                            else jnp.full(logits.shape[:-1], IGNORE))
-        metrics = {"loss": loss, "aux": aux, "acc": acc}
+        # every op of the forward carries the ``forward`` scope in its HLO
+        # ``op_name``; differentiating it puts the backward's ops under
+        # ``transpose(jvp(forward))`` (docs/observability.md)
+        with jax.named_scope("forward"):
+            (logits, aux), new_bn = model.forward_train(params, batch, mesh,
+                                                        bn_state)
+            loss, n = _lm_loss(logits, batch["labels"], smoothing=smoothing)
+            total = loss + aux_coef * aux
+            acc = top1_accuracy(logits, batch["labels"]
+                                if logits.shape[:-1] == batch["labels"].shape
+                                else jnp.full(logits.shape[:-1], IGNORE))
+            metrics = {"loss": loss, "aux": aux, "acc": acc}
         return total, (metrics, new_bn)
 
     return loss_fn
@@ -75,7 +78,7 @@ def make_loss_fn(model, *, smoothing: float = 0.1, aux_coef: float = 0.01,
 def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
                     smoothing: float = 0.1, mesh=None, comm: str = "xla",
                     bucket_mb: float = 4.0, comm_dtype: str = "bf16",
-                    grad_accum: int = 1, profile_batch=None, tracer=None,
+                    grad_accum: int = 1, profile_batch=None,
                     guard: bool = False):
     """Returns train_step(state, batch) -> (state, metrics). Not jitted —
     the caller owns jit/shardings (launcher, dryrun, tests).
@@ -111,11 +114,14 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
     ``profile_batch`` (one real batch) enables
     ``backward_profile='measured'`` for the autotuner.
 
-    ``tracer`` (an ``obs.trace.Tracer``) plants the step-timeline probes on
-    the explicit-DDP paths: forward/backward/update compute spans here,
-    per-bucket ``rs``/``ar``/``ag`` comm spans inside the ddp hooks. None
-    (the default) leaves the traced graph byte-identical to the
-    uninstrumented one — tracing is opt-in per run, not per step.
+    Every path names its phases with ``jax.named_scope`` (metadata only,
+    no run-time cost): ``forward`` around the loss and the bf16 cast of
+    the params feeding it, ``update`` around the optimizer application
+    (with the guard's commit when armed); the backward carries
+    ``transpose(jvp(forward))`` and each bucket's collective its
+    ``ar_b<k>``/``rs_b<k>``/``ag_b<k>``/``ag_g<k>`` scope (core/ddp.py).
+    A profiler trace maps each device op to its phase through the op's
+    ``op_name`` (docs/observability.md).
 
     ``guard=True`` arms the numerical-integrity sentinel (train/guard.py,
     docs/elastic.md §Numerical faults) on every path (xla, replicated,
@@ -126,8 +132,8 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
     the metrics dict gains ``gnorm``/``nonfinite``/``skipped`` scalar rows,
     and a ``lax.cond`` commits the previous state unchanged whenever the
     loss or any gradient goes nonfinite. ``guard=False`` (default) leaves
-    the step byte-identical to the unguarded graph — the same opt-in
-    contract as ``tracer``. The returned step carries ``.guarded``."""
+    the step byte-identical to the unguarded graph. The returned step
+    carries ``.guarded``."""
     comm_cfg = comm if isinstance(comm, CommConfig) else CommConfig(
         strategy=comm, bucket_mb=bucket_mb, wire_dtype=comm_dtype)
     comm, bucket_mb, comm_dtype = (comm_cfg.strategy, comm_cfg.bucket_mb,
@@ -136,16 +142,17 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
 
     def sgd_update(state: TrainState, grads, metrics, new_bn,
                    guard_in=None):
-        lr = schedule(state.step)
-        if guard_in is not None:
-            lr = lr * guard_in["lr_scale"]
-        params, mom = lars.update(state.params, grads, state.mom, lr,
-                                  opt_cfg)
-        metrics = dict(metrics, lr=lr)
-        new_state = TrainState(state.step + 1, params, mom, new_bn)
-        if guard_in is None:
-            return new_state, metrics
-        return guard_lib.apply_guard(state, new_state, metrics, grads)
+        with jax.named_scope("update"):
+            lr = schedule(state.step)
+            if guard_in is not None:
+                lr = lr * guard_in["lr_scale"]
+            params, mom = lars.update(state.params, grads, state.mom, lr,
+                                      opt_cfg)
+            metrics = dict(metrics, lr=lr)
+            new_state = TrainState(state.step + 1, params, mom, new_bn)
+            if guard_in is None:
+                return new_state, metrics
+            return guard_lib.apply_guard(state, new_state, metrics, grads)
 
     if comm == "xla":
         assert comm_cfg.sharding not in ("zero2", "zero3"), (
@@ -156,8 +163,9 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
         def xla_step(state: TrainState, batch, guard_in=None):
             lfn = (guard_lib.scale_loss(loss_fn, guard_in["loss_scale"])
                    if guard_in is not None else loss_fn)
-            p_in = (cast_to_compute(state.params) if comm_dtype == "bf16"
-                    else state.params)
+            with jax.named_scope("forward"):
+                p_in = (cast_to_compute(state.params)
+                        if comm_dtype == "bf16" else state.params)
             if grad_accum == 1:
                 (_, (metrics, new_bn)), grads = jax.value_and_grad(
                     lfn, has_aux=True)(p_in, batch, state.bn_state)
@@ -264,10 +272,8 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
         # reuses state.params (gathered at the end of the previous step).
         params = (ddp.gather_ahead_params(state.shards, plan,
                                           shard_axis=shard_axis,
-                                          wire_dtype=wire, tracer=tracer)
+                                          wire_dtype=wire)
                   if gather_ahead else state.params)
-        obs_trace.mark(tracer, "forward", "B",
-                       jax.tree.leaves(params)[:1], cat="compute")
         if overlap:
             # in-backward reduce-scatter: the wrapped loss's backward runs
             # each bucket's RS-terminal schedule the moment the group's
@@ -279,56 +285,44 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
             def sink_loss(sks, p, b, bn):
                 p = ddp.wrap_params_for_overlap(
                     p, plan, strategy=comm, axes=axes, comm_dtype=wire,
-                    use_kernel=comm_cfg.use_kernel, shard_sinks=sks,
-                    tracer=tracer)
+                    use_kernel=comm_cfg.use_kernel, shard_sinks=sks)
                 return lfn(p, b, bn)
 
-            (loss_val, (metrics, new_bn)), g_shards = jax.value_and_grad(
+            (_, (metrics, new_bn)), g_shards = jax.value_and_grad(
                 sink_loss, has_aux=True)(sinks, params, batch,
                                          state.bn_state)
             g_shards = list(g_shards)
-            # sink cotangents are the backward's true outputs here: they
-            # exist only once every group's RS has fired and reduced
-            obs_trace.mark(tracer, "backward", "E", g_shards, cat="compute")
         else:
-            (loss_val, (metrics, new_bn)), grads = jax.value_and_grad(
+            (_, (metrics, new_bn)), grads = jax.value_and_grad(
                 lfn, has_aux=True)(params, batch, state.bn_state)
-            # E on the raw (pre-reduce-scatter) grads: the RS below starts
-            # only after the whole backward ends — the testable invariant
-            obs_trace.mark(tracer, "backward", "E",
-                           jax.tree.leaves(grads), cat="compute")
             g_shards = ddp.reduce_scatter_grads(
                 grads, strategy=comm, axes=axes, plan=plan, comm_dtype=wire,
-                use_kernel=comm_cfg.use_kernel, tracer=tracer)
-        obs_trace.mark(tracer, "forward", "E", [loss_val], cat="compute")
-        obs_trace.mark(tracer, "backward", "B", [loss_val], cat="compute")
+                use_kernel=comm_cfg.use_kernel)
         if new_bn is not None:
             new_bn = jax.tree.map(lambda v: jax.lax.pmean(v, axes), new_bn)
         metrics = {k: jax.lax.pmean(v, axes) for k, v in metrics.items()}
-        lr = schedule(state.step)
-        if guard_in is not None:
-            lr = lr * guard_in["lr_scale"]
-        obs_trace.mark(tracer, "update", "B", g_shards, cat="compute")
-        p_shards, m_shards = lars.sharded_update_from_shards(
-            list(state.shards), g_shards, list(state.mom), lr, opt_cfg,
-            plan, shard_axis=shard_axis, n_shards=n_shards,
-            update_kernel=comm_cfg.update_kernel)
-        obs_trace.mark(tracer, "update", "E", p_shards, cat="compute")
-        new_params = (params if gather_ahead else
-                      ddp.all_gather_params(p_shards, plan,
-                                            shard_axis=shard_axis,
-                                            wire_dtype=wire,
-                                            tracer=tracer))
-        metrics = dict(metrics, lr=lr)
-        new_state = TrainState(state.step + 1, new_params, m_shards,
-                               new_bn, p_shards)
-        if guard_in is None:
-            return new_state, metrics
-        # the sentinel reduces over the device-local shard chunks: psum
-        # over the shard axis reassembles the global count/norm (the
-        # chunks are replicated over the other mesh axes)
-        return guard_lib.apply_guard(state, new_state, metrics, g_shards,
-                                     psum_axis=shard_axis)
+        with jax.named_scope("update"):
+            lr = schedule(state.step)
+            if guard_in is not None:
+                lr = lr * guard_in["lr_scale"]
+            p_shards, m_shards = lars.sharded_update_from_shards(
+                list(state.shards), g_shards, list(state.mom), lr, opt_cfg,
+                plan, shard_axis=shard_axis, n_shards=n_shards,
+                update_kernel=comm_cfg.update_kernel)
+            new_params = (params if gather_ahead else
+                          ddp.all_gather_params(p_shards, plan,
+                                                shard_axis=shard_axis,
+                                                wire_dtype=wire))
+            metrics = dict(metrics, lr=lr)
+            new_state = TrainState(state.step + 1, new_params, m_shards,
+                                   new_bn, p_shards)
+            if guard_in is None:
+                return new_state, metrics
+            # the sentinel reduces over the device-local shard chunks: psum
+            # over the shard axis reassembles the global count/norm (the
+            # chunks are replicated over the other mesh axes)
+            return guard_lib.apply_guard(state, new_state, metrics, g_shards,
+                                         psum_axis=shard_axis)
 
     def zero3_step(state: TrainState, batch, guard_in=None):
         lfn = (guard_lib.scale_loss(loss_fn, guard_in["loss_scale"])
@@ -343,64 +337,53 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
         # full activation checkpointing: 2x forward compute, O(largest
         # group) params live in the backward too); gather='ahead' retains
         # the forward copies as ordinary residuals.
-        obs_trace.mark(tracer, "forward", "B", list(state.shards)[:1],
-                       cat="compute")
         if overlap:
             sinks = ddp.make_shard_sinks(plan, n_shards)
 
             def sink_loss3(sks, shards, b, bn):
                 params = ddp.jit_gather_params(
-                    shards, plan, shard_axis=shard_axis, wire_dtype=wire,
-                    tracer=tracer)
+                    shards, plan, shard_axis=shard_axis, wire_dtype=wire)
                 p = ddp.wrap_params_for_overlap(
                     params, plan, strategy=comm, axes=axes, comm_dtype=wire,
-                    use_kernel=comm_cfg.use_kernel, shard_sinks=sks,
-                    tracer=tracer)
+                    use_kernel=comm_cfg.use_kernel, shard_sinks=sks)
                 return lfn(p, b, bn)
 
             inner = (jax.checkpoint(sink_loss3)
                      if gather_mode == "per_group" else sink_loss3)
-            (loss_val, (metrics, new_bn)), g_shards = jax.value_and_grad(
+            (_, (metrics, new_bn)), g_shards = jax.value_and_grad(
                 inner, has_aux=True)(sinks, state.shards, batch,
                                      state.bn_state)
             g_shards = list(g_shards)
-            obs_trace.mark(tracer, "backward", "E", g_shards, cat="compute")
         else:
             # non-overlapped fallback: gather outside the differentiated
             # function (the full tree is a step-transient, still never in
             # TrainState) and scatter after the backward. Remat would not
             # cover the gathers here, so 'per_group' degrades to retain.
             params = ddp.jit_gather_params(
-                state.shards, plan, shard_axis=shard_axis, wire_dtype=wire,
-                tracer=tracer)
-            (loss_val, (metrics, new_bn)), grads = jax.value_and_grad(
+                state.shards, plan, shard_axis=shard_axis, wire_dtype=wire)
+            (_, (metrics, new_bn)), grads = jax.value_and_grad(
                 lfn, has_aux=True)(params, batch, state.bn_state)
-            obs_trace.mark(tracer, "backward", "E",
-                           jax.tree.leaves(grads), cat="compute")
             g_shards = ddp.reduce_scatter_grads(
                 grads, strategy=comm, axes=axes, plan=plan, comm_dtype=wire,
-                use_kernel=comm_cfg.use_kernel, tracer=tracer)
-        obs_trace.mark(tracer, "forward", "E", [loss_val], cat="compute")
-        obs_trace.mark(tracer, "backward", "B", [loss_val], cat="compute")
+                use_kernel=comm_cfg.use_kernel)
         if new_bn is not None:
             new_bn = jax.tree.map(lambda v: jax.lax.pmean(v, axes), new_bn)
         metrics = {k: jax.lax.pmean(v, axes) for k, v in metrics.items()}
-        lr = schedule(state.step)
-        if guard_in is not None:
-            lr = lr * guard_in["lr_scale"]
-        obs_trace.mark(tracer, "update", "B", g_shards, cat="compute")
-        p_shards, m_shards = lars.sharded_update_from_shards(
-            list(state.shards), g_shards, list(state.mom), lr, opt_cfg,
-            plan, shard_axis=shard_axis, n_shards=n_shards,
-            update_kernel=comm_cfg.update_kernel)
-        obs_trace.mark(tracer, "update", "E", p_shards, cat="compute")
-        metrics = dict(metrics, lr=lr)
-        new_state = TrainState(state.step + 1, None, m_shards, new_bn,
-                               p_shards)
-        if guard_in is None:
-            return new_state, metrics
-        return guard_lib.apply_guard(state, new_state, metrics, g_shards,
-                                     psum_axis=shard_axis)
+        with jax.named_scope("update"):
+            lr = schedule(state.step)
+            if guard_in is not None:
+                lr = lr * guard_in["lr_scale"]
+            p_shards, m_shards = lars.sharded_update_from_shards(
+                list(state.shards), g_shards, list(state.mom), lr, opt_cfg,
+                plan, shard_axis=shard_axis, n_shards=n_shards,
+                update_kernel=comm_cfg.update_kernel)
+            metrics = dict(metrics, lr=lr)
+            new_state = TrainState(state.step + 1, None, m_shards, new_bn,
+                                   p_shards)
+            if guard_in is None:
+                return new_state, metrics
+            return guard_lib.apply_guard(state, new_state, metrics, g_shards,
+                                         psum_axis=shard_axis)
 
     def zero2_step(state: TrainState, batch, guard_in=None):
         lfn = (guard_lib.scale_loss(loss_fn, guard_in["loss_scale"])
@@ -414,67 +397,59 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
         # all-gather (fp32: the masters must never round-trip through
         # the wire dtype) writes the updated replica back.
         params = state.params
-        obs_trace.mark(tracer, "forward", "B",
-                       jax.tree.leaves(params)[:1], cat="compute")
         if overlap:
             sinks = ddp.make_shard_sinks(plan, n_shards)
 
             def sink_loss2(sks, p, b, bn):
                 p = ddp.wrap_params_for_overlap(
                     p, plan, strategy=comm, axes=axes, comm_dtype=wire,
-                    use_kernel=comm_cfg.use_kernel, shard_sinks=sks,
-                    tracer=tracer)
+                    use_kernel=comm_cfg.use_kernel, shard_sinks=sks)
                 return lfn(p, b, bn)
 
-            (loss_val, (metrics, new_bn)), g_shards = jax.value_and_grad(
+            (_, (metrics, new_bn)), g_shards = jax.value_and_grad(
                 sink_loss2, has_aux=True)(sinks, params, batch,
                                           state.bn_state)
             g_shards = list(g_shards)
-            obs_trace.mark(tracer, "backward", "E", g_shards, cat="compute")
         else:
-            (loss_val, (metrics, new_bn)), grads = jax.value_and_grad(
+            (_, (metrics, new_bn)), grads = jax.value_and_grad(
                 lfn, has_aux=True)(params, batch, state.bn_state)
-            obs_trace.mark(tracer, "backward", "E",
-                           jax.tree.leaves(grads), cat="compute")
             g_shards = ddp.reduce_scatter_grads(
                 grads, strategy=comm, axes=axes, plan=plan, comm_dtype=wire,
-                use_kernel=comm_cfg.use_kernel, tracer=tracer)
-        obs_trace.mark(tracer, "forward", "E", [loss_val], cat="compute")
-        obs_trace.mark(tracer, "backward", "B", [loss_val], cat="compute")
+                use_kernel=comm_cfg.use_kernel)
         if new_bn is not None:
             new_bn = jax.tree.map(lambda v: jax.lax.pmean(v, axes), new_bn)
         metrics = {k: jax.lax.pmean(v, axes) for k, v in metrics.items()}
-        lr = schedule(state.step)
-        if guard_in is not None:
-            lr = lr * guard_in["lr_scale"]
-        obs_trace.mark(tracer, "update", "B", g_shards, cat="compute")
-        # transient local master shards: pack the replica into the bucket
-        # buffers and slice this device's ring chunk (the same chunk the
-        # reduce-scatter left here — comm.primitives.shard_index); each
-        # slice is O(N/n) live and dies once the packed update consumes it
-        from repro.comm.primitives import shard_index
-        k = shard_index(shard_axis)
-        p_shards = []
-        for buf in bucketing.pack(params, plan, dtype=jnp.float32):
-            padded = bucketing.pad_to_shards(buf, n_shards)
-            c = padded.shape[0] // n_shards
-            p_shards.append(jax.lax.dynamic_slice(padded, (k * c,), (c,)))
-        p_shards, m_shards = lars.sharded_update_from_shards(
-            p_shards, g_shards, list(state.mom), lr, opt_cfg,
-            plan, shard_axis=shard_axis, n_shards=n_shards,
-            update_kernel=comm_cfg.update_kernel)
-        obs_trace.mark(tracer, "update", "E", p_shards, cat="compute")
-        new_params = ddp.all_gather_params(p_shards, plan,
-                                           shard_axis=shard_axis,
-                                           wire_dtype=jnp.float32,
-                                           tracer=tracer)
-        metrics = dict(metrics, lr=lr)
-        new_state = TrainState(state.step + 1, new_params, m_shards,
-                               new_bn, None)
-        if guard_in is None:
-            return new_state, metrics
-        return guard_lib.apply_guard(state, new_state, metrics, g_shards,
-                                     psum_axis=shard_axis)
+        with jax.named_scope("update"):
+            lr = schedule(state.step)
+            if guard_in is not None:
+                lr = lr * guard_in["lr_scale"]
+            # transient local master shards: pack the replica into the
+            # bucket buffers and slice this device's ring chunk (the same
+            # chunk the reduce-scatter left here —
+            # comm.primitives.shard_index); each slice is O(N/n) live and
+            # dies once the packed update uses it
+            from repro.comm.primitives import shard_index
+            k = shard_index(shard_axis)
+            p_shards = []
+            for buf in bucketing.pack(params, plan, dtype=jnp.float32):
+                padded = bucketing.pad_to_shards(buf, n_shards)
+                c = padded.shape[0] // n_shards
+                p_shards.append(
+                    jax.lax.dynamic_slice(padded, (k * c,), (c,)))
+            p_shards, m_shards = lars.sharded_update_from_shards(
+                p_shards, g_shards, list(state.mom), lr, opt_cfg,
+                plan, shard_axis=shard_axis, n_shards=n_shards,
+                update_kernel=comm_cfg.update_kernel)
+            new_params = ddp.all_gather_params(p_shards, plan,
+                                               shard_axis=shard_axis,
+                                               wire_dtype=jnp.float32)
+            metrics = dict(metrics, lr=lr)
+            new_state = TrainState(state.step + 1, new_params, m_shards,
+                                   new_bn, None)
+            if guard_in is None:
+                return new_state, metrics
+            return guard_lib.apply_guard(state, new_state, metrics, g_shards,
+                                         psum_axis=shard_axis)
 
     def local_step(state: TrainState, batch, guard_in=None):
         if sharding == "zero3":
@@ -485,44 +460,29 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
             return sharded_step(state, batch, guard_in)
         lfn = (guard_lib.scale_loss(loss_fn, guard_in["loss_scale"])
                if guard_in is not None else loss_fn)
-        obs_trace.mark(tracer, "forward", "B",
-                       jax.tree.leaves(state.params)[:1], cat="compute")
         if overlap:
             def wrapped_loss(params, b, bn):
                 p = ddp.wrap_params_for_overlap(
                     params, plan, strategy=comm, axes=axes, comm_dtype=wire,
-                    use_kernel=comm_cfg.use_kernel, tracer=tracer)
+                    use_kernel=comm_cfg.use_kernel)
                 return lfn(p, b, bn)
-            (loss_val, (metrics, new_bn)), grads = jax.value_and_grad(
+            (_, (metrics, new_bn)), grads = jax.value_and_grad(
                 wrapped_loss, has_aux=True)(state.params, batch,
                                             state.bn_state)
-            # the param cotangents pass through the in-backward all-reduce,
-            # so this backward span's window includes the overlapped comm
-            obs_trace.mark(tracer, "backward", "E",
-                           jax.tree.leaves(grads), cat="compute")
         else:
-            (loss_val, (metrics, new_bn)), grads = jax.value_and_grad(
+            (_, (metrics, new_bn)), grads = jax.value_and_grad(
                 lfn, has_aux=True)(state.params, batch, state.bn_state)
-            obs_trace.mark(tracer, "backward", "E",
-                           jax.tree.leaves(grads), cat="compute")
             grads = ddp.allreduce_grads(grads, strategy=comm, axes=axes,
                                         plan=plan, comm_dtype=wire,
-                                        use_kernel=comm_cfg.use_kernel,
-                                        tracer=tracer)
-        obs_trace.mark(tracer, "forward", "E", [loss_val], cat="compute")
-        obs_trace.mark(tracer, "backward", "B", [loss_val], cat="compute")
+                                        use_kernel=comm_cfg.use_kernel)
         if new_bn is not None:
             # BN batch stats stay local (paper §III-A.2); only the moving-
             # average *buffers* are averaged so the SPMD state is replicated
             new_bn = jax.tree.map(lambda v: jax.lax.pmean(v, axes), new_bn)
         metrics = {k: jax.lax.pmean(v, axes) for k, v in metrics.items()}
-        obs_trace.mark(tracer, "update", "B",
-                       jax.tree.leaves(grads)[:1], cat="compute")
         # guarded: the grads are the all-reduced means (identical on every
         # device), so the sentinel inside sgd_update needs no psum
         state, metrics = sgd_update(state, grads, metrics, new_bn, guard_in)
-        obs_trace.mark(tracer, "update", "E",
-                       jax.tree.leaves(state.params), cat="compute")
         return state, metrics
 
     metric_keys = ("loss", "aux", "acc", "lr")
