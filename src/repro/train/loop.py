@@ -140,16 +140,34 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
     (``make_train_step(..., guard=True)``). A guarded step with
     ``guard=None`` runs under the default ``GuardConfig()``.
 
+    Without the watchdog or the guard, the loop keeps one step in flight:
+    step i is dispatched on the not-yet-ready state step i-1 returned, and
+    only then does the host wait on step i-1 and read its metrics back, so
+    the host's work between two steps overlaps the device's. Never more
+    than one: each batch fetch still follows exactly one more finished
+    step. Checkpoints, evals, the preemption drain and the end of the run
+    drain the step in flight first, so they see the state and the history
+    they always did; the state returned is ready. The watchdog (each step
+    under a timeout, no donation) and the guard (whose sentinel readback
+    decides the next step) run each step to its end before the next.
+    ``obs.loop.overlapped_total`` counts the steps dispatched with the
+    previous one in flight, ``obs.loop.drain_total`` the drains; both are
+    emitted on logged steps and at drains.
+
     Each step runs inside a ``jax.profiler.StepTraceAnnotation``
     (``train_step``, with its step number) holding the host spans
-    ``loop.batch`` (the batch function), ``loop.release`` (dropping the
-    previous step's input state), ``loop.dispatch`` (the jitted call
-    until it returns), ``loop.wait`` (``block_until_ready``),
-    ``loop.readback`` (every device-to-host read of the step's metrics and
-    the log line built from them), ``loop.checkpoint`` and ``loop.eval``.
-    They cost well under a microsecond each unless a profiler is running,
-    and then land on its host plane, on the device trace's clock
-    (docs/observability.md)."""
+    ``loop.batch`` (the batch function), ``loop.dispatch`` (the jitted
+    call until it returns), ``loop.release`` (dropping the state the step
+    consumed, which destroys every array handle of it), ``loop.wait``
+    (``block_until_ready`` on the previous step's metrics, or at a drain
+    on this step's metrics and state), ``loop.readback`` (every
+    device-to-host read of a logged step's metrics and the log line built
+    from them, one step after that step's dispatch or at a drain),
+    ``loop.checkpoint`` and ``loop.eval``. On the synchronous paths
+    ``loop.release`` comes before ``loop.dispatch`` and ``loop.wait`` and
+    ``loop.readback`` are the step's own. The spans cost well under a
+    microsecond each unless a profiler is running, and then land on its
+    host plane, on the device trace's clock (docs/observability.md)."""
     mlperf_log("run_start")
     mlperf_log("run_set_random_seed", seed)
     injector = (faults if isinstance(faults, FaultInjector)
@@ -182,6 +200,52 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
     skips = 0                 # consecutive sentinel skips
     rollbacks = 0             # ring rollbacks used
     restores = 0              # guard checkpoint restores used
+    pipelined = not (watchdog or guarded)
+    in_flight = None          # (step, metrics) dispatched, not waited on
+    unsent = {"obs.loop.overlapped_total": 0, "obs.loop.drain_total": 0}
+
+    def logged(step: int) -> bool:
+        return bool(log_every) and (step % log_every == 0
+                                    or step == steps - 1)
+
+    def log_step(step: int, metrics) -> None:
+        with TraceAnnotation("loop.readback"):
+            m = {k: float(v) for k, v in metrics.items()}
+            history.append({"step": step, **m})
+            mlperf_log("train_step",
+                       {"step": step, "loss": round(m["loss"], 4),
+                        "lr": round(m.get("lr", 0.0), 6)})
+            if guarded:
+                obs_metrics.gauge("obs.guard.gnorm", m["gnorm"],
+                                  where=_WHERE, step=step)
+
+    def emit_loop_counters(step: int) -> None:
+        # StdoutSink prints every event: counters go out on logged steps
+        # and at drains, with what they gained since
+        for name, inc in unsent.items():
+            if inc:
+                obs_metrics.counter(name, inc, where=_WHERE, step=step)
+                unsent[name] = 0
+
+    def settle(step: int, metrics, *ready) -> None:
+        """Wait for a dispatched step (its metrics, and ``ready``), then
+        read it back if it is a logged step."""
+        with TraceAnnotation("loop.wait"):
+            jax.block_until_ready((metrics, ready))
+        if logged(step):
+            log_step(step, metrics)
+            emit_loop_counters(step)
+
+    def drain() -> None:
+        """A synchronous boundary: the step in flight finishes, and is
+        read back, before anything reads the state."""
+        nonlocal in_flight
+        if in_flight is not None:
+            step, metrics = in_flight
+            in_flight = None
+            unsent["obs.loop.drain_total"] += 1
+            settle(step, metrics, state)
+            emit_loop_counters(step)
 
     def save_ckpt(s: TrainState) -> None:
         nonlocal last_saved_step
@@ -222,174 +286,181 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
             with StepTraceAnnotation("train_step", step_num=i):
                 with TraceAnnotation("loop.batch"):
                     batch = injector.poison_batch(batch_fn(state.step), i)
-                with TraceAnnotation("loop.release"):
-                    # the previous step's closure holds that step's input
-                    # state: dropping it destroys every array handle of it,
-                    # host time in which the device idles
-                    run_step = None
-                guard_in = None
-                if guarded:
-                    import numpy as np
-                    scale = (1.0 if rewarm_start is None
-                             else rewarm(i - rewarm_start))
-                    guard_in = {
-                        "lr_scale": np.float32(scale),
-                        "loss_scale": np.float32(injector.loss_scale(i))}
-
-                def run_step(state=state, batch=batch, i=i,
-                             guard_in=guard_in):
+                if pipelined:
                     injector.on_step(i)
                     with TraceAnnotation("loop.dispatch"):
-                        out = (step_fn(state, batch, guard_in) if guarded
-                               else step_fn(state, batch))
-                    with TraceAnnotation("loop.wait"):
-                        return jax.block_until_ready(out)
+                        donated = state
+                        state, metrics = step_fn(state, batch)
+                    with TraceAnnotation("loop.release"):
+                        # destroys every array handle of the donated state:
+                        # host time the step in flight now covers
+                        del donated
+                    if in_flight is not None:
+                        unsent["obs.loop.overlapped_total"] += 1
+                        settle(*in_flight)
+                    in_flight = (i, metrics)
+                else:
+                    with TraceAnnotation("loop.release"):
+                        # the previous step's closure holds that step's input
+                        # state: dropping it destroys every array handle of it,
+                        # host time in which the device idles
+                        run_step = None
+                    guard_in = None
+                    if guarded:
+                        import numpy as np
+                        scale = (1.0 if rewarm_start is None
+                                 else rewarm(i - rewarm_start))
+                        guard_in = {
+                            "lr_scale": np.float32(scale),
+                            "loss_scale": np.float32(injector.loss_scale(i))}
 
-                try:
-                    state, metrics = _call_with_timeout(run_step,
-                                                        step_timeout_s)
-                    retries = 0
-                except StepTimeoutError as e:
-                    retries += 1
-                    obs_metrics.counter("obs.watchdog_timeout_total",
-                                        where="repro/train/loop.py", step=i)
-                    mlperf_log("watchdog_timeout",
-                               {"step": i, "attempt": retries,
-                                "timeout_s": step_timeout_s})
-                    history.append({"step": i, "watchdog_timeout": retries})
-                    if retries > max_step_retries:
-                        raise RuntimeError(
-                            f"step {i} timed out {retries} times "
-                            f"(budget {step_timeout_s:.1f}s each) — giving "
-                            f"up after bounded retries") from e
-                    if ckpt_dir:
-                        try:
-                            state = ckpt.load(state, ckpt_dir, tag=None)
-                            i = int(state.step)
-                            mlperf_log("watchdog_restore",
-                                       {"resume_step": i})
-                            history.append({"step": i,
-                                            "watchdog_restore": 1})
-                        except ckpt.CheckpointError as err:
-                            # used to be a bare print that bypassed the tag
-                            # stream; now a first-class event on every sink
-                            mlperf_log("watchdog_no_checkpoint",
-                                       {"step": i, "error": str(err),
-                                        "action": "retrying with the "
-                                                  "in-memory state"})
-                    time.sleep(min(retry_backoff_s * 2 ** (retries - 1),
-                                   30.0))
-                    continue
-                if guarded:
-                    # recovery ladder (docs/elastic.md §Numerical faults)
-                    with TraceAnnotation("loop.readback"):
-                        g_loss = float(metrics["loss"])
-                        g_gnorm = float(metrics["gnorm"])
-                        g_skipped = float(metrics["skipped"])
-                    reason = None
-                    if g_skipped > 0:
-                        # rung 1: the in-graph sentinel refused the update —
-                        # state (and state.step) are unchanged, replay step i
-                        skips += 1
-                        obs_metrics.counter("obs.guard.skip_total",
-                                            where=_WHERE, step=i)
-                        with TraceAnnotation("loop.readback"):
-                            nonfinite = int(float(metrics["nonfinite"]))
-                        mlperf_log("guard_skip",
-                                   {"step": i, "attempt": skips,
-                                    "nonfinite": nonfinite})
-                        history.append({"step": i, "guard_skip": skips})
-                        if skips <= gcfg.max_skips:
-                            if not preempted.is_set():
-                                continue
-                            reason = "preempted mid-skip"
-                        else:
-                            reason = (f"{skips} consecutive nonfinite steps "
-                                      f"at step {i}")
-                    else:
-                        skips = 0
-                        if detector.observe(g_loss, g_gnorm) != "ok":
-                            reason = (
-                                f"divergence at step {i}: loss "
-                                f"{g_loss:.4g}, grad-norm {g_gnorm:.4g} "
-                                f"vs EMA {detector.ema_gnorm or 0.0:.4g}")
-                    if reason == "preempted mid-skip":
-                        # a skipped step committed nothing; drain like the
-                        # normal preemption path below
-                        mlperf_log("preempt_drain", {"step": i})
-                        if ckpt_dir and last_saved_step != int(state.step):
-                            save_ckpt(state)
-                        break
-                    if reason is not None:
-                        recovered = False
-                        snap = ring.newest()
-                        if snap is not None and \
-                                rollbacks < gcfg.max_rollbacks:
-                            # rung 2: in-memory rollback, no checkpoint IO
-                            rollbacks += 1
-                            rstep, hstate = snap
-                            state = RollbackRing.restore(hstate)
-                            i = int(state.step)
-                            if gcfg.rewarmup_steps:
-                                rewarm_start = i
-                            obs_metrics.counter("obs.guard.rollback_total",
-                                                where=_WHERE, step=i)
-                            mlperf_log("guard_rollback",
-                                       {"resume_step": i, "used": rollbacks,
-                                        "reason": reason})
-                            history.append({"step": i,
-                                            "guard_rollback": rollbacks})
-                            if ckpt_dir:
-                                # guard-escalation save: step-tagged, so
-                                # keep_last_k retention can prune a spiky
-                                # run's trail (hand-named tags stay spared)
-                                save_ckpt(state)
-                            recovered = True
-                        elif ckpt_dir and restores < gcfg.max_restores:
-                            # rung 3: checkpoint restore
+                    def run_step(state=state, batch=batch, i=i,
+                                 guard_in=guard_in):
+                        injector.on_step(i)
+                        with TraceAnnotation("loop.dispatch"):
+                            out = (step_fn(state, batch, guard_in) if guarded
+                                   else step_fn(state, batch))
+                        with TraceAnnotation("loop.wait"):
+                            return jax.block_until_ready(out)
+
+                    try:
+                        state, metrics = _call_with_timeout(run_step,
+                                                            step_timeout_s)
+                        retries = 0
+                    except StepTimeoutError as e:
+                        retries += 1
+                        obs_metrics.counter("obs.watchdog_timeout_total",
+                                            where="repro/train/loop.py", step=i)
+                        mlperf_log("watchdog_timeout",
+                                   {"step": i, "attempt": retries,
+                                    "timeout_s": step_timeout_s})
+                        history.append({"step": i, "watchdog_timeout": retries})
+                        if retries > max_step_retries:
+                            raise RuntimeError(
+                                f"step {i} timed out {retries} times "
+                                f"(budget {step_timeout_s:.1f}s each) — giving "
+                                f"up after bounded retries") from e
+                        if ckpt_dir:
                             try:
                                 state = ckpt.load(state, ckpt_dir, tag=None)
-                                restores += 1
+                                i = int(state.step)
+                                mlperf_log("watchdog_restore",
+                                           {"resume_step": i})
+                                history.append({"step": i,
+                                                "watchdog_restore": 1})
+                            except ckpt.CheckpointError as err:
+                                # used to be a bare print that bypassed the tag
+                                # stream; now a first-class event on every sink
+                                mlperf_log("watchdog_no_checkpoint",
+                                           {"step": i, "error": str(err),
+                                            "action": "retrying with the "
+                                                      "in-memory state"})
+                        time.sleep(min(retry_backoff_s * 2 ** (retries - 1),
+                                       30.0))
+                        continue
+                    if guarded:
+                        # recovery ladder (docs/elastic.md §Numerical faults)
+                        with TraceAnnotation("loop.readback"):
+                            g_loss = float(metrics["loss"])
+                            g_gnorm = float(metrics["gnorm"])
+                            g_skipped = float(metrics["skipped"])
+                        reason = None
+                        if g_skipped > 0:
+                            # rung 1: the in-graph sentinel refused the update —
+                            # state (and state.step) are unchanged, replay step i
+                            skips += 1
+                            obs_metrics.counter("obs.guard.skip_total",
+                                                where=_WHERE, step=i)
+                            with TraceAnnotation("loop.readback"):
+                                nonfinite = int(float(metrics["nonfinite"]))
+                            mlperf_log("guard_skip",
+                                       {"step": i, "attempt": skips,
+                                        "nonfinite": nonfinite})
+                            history.append({"step": i, "guard_skip": skips})
+                            if skips <= gcfg.max_skips:
+                                if not preempted.is_set():
+                                    continue
+                                reason = "preempted mid-skip"
+                            else:
+                                reason = (f"{skips} consecutive nonfinite steps "
+                                          f"at step {i}")
+                        else:
+                            skips = 0
+                            if detector.observe(g_loss, g_gnorm) != "ok":
+                                reason = (
+                                    f"divergence at step {i}: loss "
+                                    f"{g_loss:.4g}, grad-norm {g_gnorm:.4g} "
+                                    f"vs EMA {detector.ema_gnorm or 0.0:.4g}")
+                        if reason == "preempted mid-skip":
+                            # a skipped step committed nothing; drain like the
+                            # normal preemption path below
+                            mlperf_log("preempt_drain", {"step": i})
+                            if ckpt_dir and last_saved_step != int(state.step):
+                                save_ckpt(state)
+                            break
+                        if reason is not None:
+                            recovered = False
+                            snap = ring.newest()
+                            if snap is not None and \
+                                    rollbacks < gcfg.max_rollbacks:
+                                # rung 2: in-memory rollback, no checkpoint IO
+                                rollbacks += 1
+                                rstep, hstate = snap
+                                state = RollbackRing.restore(hstate)
                                 i = int(state.step)
                                 if gcfg.rewarmup_steps:
                                     rewarm_start = i
-                                obs_metrics.counter(
-                                    "obs.guard.restore_total",
-                                    where=_WHERE, step=i)
-                                mlperf_log("guard_ckpt_restore",
-                                           {"resume_step": i,
+                                obs_metrics.counter("obs.guard.rollback_total",
+                                                    where=_WHERE, step=i)
+                                mlperf_log("guard_rollback",
+                                           {"resume_step": i, "used": rollbacks,
                                             "reason": reason})
                                 history.append({"step": i,
-                                                "guard_restore": 1})
+                                                "guard_rollback": rollbacks})
+                                if ckpt_dir:
+                                    # guard-escalation save: step-tagged, so
+                                    # keep_last_k retention can prune a spiky
+                                    # run's trail (hand-named tags stay spared)
+                                    save_ckpt(state)
                                 recovered = True
-                            except ckpt.CheckpointError as err:
-                                mlperf_log("guard_no_checkpoint",
-                                           {"step": i, "error": str(err)})
-                        if not recovered:
-                            # rung 4: bounded-retry exhaustion
-                            raise RuntimeError(
-                                f"numerical guard exhausted its recovery "
-                                f"ladder ({rollbacks} rollbacks, {restores} "
-                                f"checkpoint restores) — {reason}")
-                        skips = 0
-                        continue
-                    if ring is not None and int(state.step) % max(
-                            gcfg.snapshot_every, 1) == 0:
-                        # snapshot only a state that passed sentinel AND
-                        # detector: a spiked state is never a restore target
-                        ring.snapshot(state)
-                if log_every and (i % log_every == 0 or i == steps - 1):
-                    with TraceAnnotation("loop.readback"):
-                        m = {k: float(v) for k, v in metrics.items()}
-                        history.append({"step": i, **m})
-                        mlperf_log("train_step",
-                                   {"step": i, "loss": round(m["loss"], 4),
-                                    "lr": round(m.get("lr", 0.0), 6)})
-                        if guarded:
-                            obs_metrics.gauge("obs.guard.gnorm", m["gnorm"],
-                                              where=_WHERE, step=i)
+                            elif ckpt_dir and restores < gcfg.max_restores:
+                                # rung 3: checkpoint restore
+                                try:
+                                    state = ckpt.load(state, ckpt_dir, tag=None)
+                                    restores += 1
+                                    i = int(state.step)
+                                    if gcfg.rewarmup_steps:
+                                        rewarm_start = i
+                                    obs_metrics.counter(
+                                        "obs.guard.restore_total",
+                                        where=_WHERE, step=i)
+                                    mlperf_log("guard_ckpt_restore",
+                                               {"resume_step": i,
+                                                "reason": reason})
+                                    history.append({"step": i,
+                                                    "guard_restore": 1})
+                                    recovered = True
+                                except ckpt.CheckpointError as err:
+                                    mlperf_log("guard_no_checkpoint",
+                                               {"step": i, "error": str(err)})
+                            if not recovered:
+                                # rung 4: bounded-retry exhaustion
+                                raise RuntimeError(
+                                    f"numerical guard exhausted its recovery "
+                                    f"ladder ({rollbacks} rollbacks, {restores} "
+                                    f"checkpoint restores) — {reason}")
+                            skips = 0
+                            continue
+                        if ring is not None and int(state.step) % max(
+                                gcfg.snapshot_every, 1) == 0:
+                            # snapshot only a state that passed sentinel AND
+                            # detector: a spiked state is never a restore target
+                            ring.snapshot(state)
+                    if logged(i):
+                        log_step(i, metrics)
                 if eval_every and eval_fn is not None \
                         and (i + 1) % eval_every == 0:
+                    drain()
                     with TraceAnnotation("loop.eval"):
                         mlperf_log("eval_start")
                         eb = eval_batch_fn(state.step + 100_000)
@@ -404,6 +475,7 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
                                                       for k, v in em.items()}})
                 i += 1
                 if ckpt_dir and ckpt_every and i % ckpt_every == 0:
+                    drain()
                     save_ckpt(state)
                 if preempted.is_set():
                     # announced preemption: the in-flight step has drained
@@ -412,10 +484,15 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
                     # drained step that also landed on the ckpt_every
                     # cadence was saved two lines up and must not commit
                     # the same step twice.
+                    drain()
                     mlperf_log("preempt_drain", {"step": i})
                     if ckpt_dir and last_saved_step != int(state.step):
                         save_ckpt(state)
                     break
+                if i == steps:
+                    # the run's end: the state returned is ready and the
+                    # history holds every step
+                    drain()
         if ckpt_dir and last_saved_step != int(state.step):
             # run_stop tail: steps not a multiple of ckpt_every (or no
             # periodic cadence at all) must still leave a final checkpoint

@@ -9,9 +9,11 @@ mesh in one subprocess (jax fixes the device count at import), with the
 reduced ResNet cut to one block per stage to keep four compiles short.
 
 The training loop wraps each step in a ``train_step`` step annotation
-holding ``loop.batch``, ``loop.release``, ``loop.dispatch``, ``loop.wait``
-and, on logged steps, ``loop.readback``; a CPU profile of ``loop.train``
-must hold them in that order. ``launch.train --trace DIR`` writes the profile.
+holding ``loop.batch``, ``loop.dispatch``, ``loop.release``, ``loop.wait``
+on the step before (the loop keeps one step in flight) and
+``loop.readback`` of a logged step one step later or at the final drain;
+a CPU profile of ``loop.train`` must hold them in that order.
+``launch.train --trace DIR`` writes the profile.
 """
 import glob
 import json
@@ -174,10 +176,12 @@ def _host_events(profile_dir: str):
 
 
 def test_loop_spans_nest_in_each_step_in_order(tmp_path):
-    """Three steps under the profiler: three ``train_step`` annotations,
-    each holding ``loop.batch``, ``loop.release``, ``loop.dispatch`` and
-    ``loop.wait`` in that order, and ``loop.readback`` on the logged steps
-    (0 and 2)."""
+    """Three steps under the profiler, one in flight: three ``train_step``
+    annotations, each holding ``loop.batch``, ``loop.dispatch`` and
+    ``loop.release`` in that order, then ``loop.wait`` on the previous
+    step (none in step 0, which has none); ``loop.readback`` of logged
+    step 0 in step 1, and of logged step 2 at the drain that ends step 2,
+    after a second ``loop.wait``."""
     def train_step(state, batch):
         w = state.params["w"] - 0.1 * batch["x"]
         return (state._replace(step=state.step + 1, params={"w": w}),
@@ -193,13 +197,13 @@ def test_loop_spans_nest_in_each_step_in_order(tmp_path):
     steps = sorted((e for e in events if e[2] == "train_step"),
                    key=lambda e: e[0])
     assert [int(e[3]) for e in steps] == [0, 1, 2]
+    head = ["loop.batch", "loop.dispatch", "loop.release"]
+    want = [head, head + ["loop.wait", "loop.readback"],
+            head + ["loop.wait", "loop.wait", "loop.readback"]]
     for i, (s0, s1, _, _) in enumerate(steps):
         inside = sorted((e for e in events if e[2].startswith("loop.")
                          and s0 <= e[0] and e[1] <= s1), key=lambda e: e[0])
-        order = [e[2] for e in inside]
-        want = ["loop.batch", "loop.release", "loop.dispatch", "loop.wait"]
-        assert order[:4] == want, order
-        assert ("loop.readback" in order) is (i != 1), order
+        assert [e[2] for e in inside] == want[i]
     # no span of the loop lies outside a step
     assert all(any(s0 <= e[0] and e[1] <= s1 for s0, s1, _, _ in steps)
                for e in events if e[2].startswith("loop."))
