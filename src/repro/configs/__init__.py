@@ -18,6 +18,7 @@ _REGISTRY = {
     "internvl2-2b":     "internvl2_2b",
     "qwen1.5-0.5b":     "qwen1_5_0_5b",
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "qwen2-moe-a2.7b":  "qwen2_moe_a2_7b",
     "resnet50":         "resnet50",   # the paper's own architecture
 }

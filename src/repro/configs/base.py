@@ -180,12 +180,26 @@ class CommConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. With ``n_held`` 0 every expert is computed, over the
+    ``model`` mesh axis with a capacity per expert (``capacity_factor``;
+    tokens past it are dropped). With ``n_held`` > 0 the layer holds
+    experts ``[held_offset, held_offset + n_held)`` of the ``n_routed`` its
+    router scores, one chip's share under expert parallelism, and computes
+    their part of the result for every token routed to them: no capacity,
+    nothing dropped (``models/moe.py``)."""
     n_routed: int = 0          # number of routed experts
     top_k: int = 0             # experts per token
     n_shared: int = 0          # always-on shared experts
     d_expert: int = 0          # per-expert FFN hidden dim
     router_aux_coef: float = 0.01  # load-balance loss coefficient
     capacity_factor: float = 1.25  # per-expert buffer = T*top_k/E * this
+    first_k_dense: int = 0     # leading layers with a dense SwiGLU FFN
+    d_dense: int = 0           # their FFN width
+    norm_topk: bool = True     # renormalise the top-k gates to sum to 1
+    routed_scale: float = 1.0  # the gates' factor (routed_scaling_factor)
+    seq_aux: bool = False      # balance loss per sequence, then the mean
+    n_held: int = 0            # experts this chip holds (0: all, with EP)
+    held_offset: int = 0       # the first of them
 
 
 @dataclass(frozen=True)
@@ -195,6 +209,18 @@ class SSMConfig:
     expand: int = 2            # d_inner = expand * d_model
     head_dim: int = 64         # mamba2 head dim
     chunk: int = 64            # SSD chunk length (train-time parallel form)
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (Peng et al. 2023, arXiv:2309.00071), as
+    DeepSeek-V2 publishes it (``rope_scaling`` of its ``config.json``)."""
+    factor: float = 40.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+    original_max_position: int = 4096
 
 
 @dataclass(frozen=True)
@@ -241,6 +267,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_yarn: Optional[YarnConfig] = None  # YaRN scaling of the rope
     rms_eps: float = 1e-6
     tie_embeddings: bool = False
     sliding_window: int = 0      # 0 = full attention; >0 = window size
@@ -253,6 +280,10 @@ class ModelConfig:
     # distribution hints
     fsdp: bool = False           # additionally shard master params over 'data'
     remat: bool = True           # activation checkpointing on the layer scan
+    # LARS takes one trust ratio per published tensor (each layer's, each
+    # expert's) and leaves tensors of rank <= 1 unscaled; False: one ratio
+    # per stored array, a layer stack included (core/lars.py)
+    lars_per_published_tensor: bool = False
     attn_chunk: int = 1024       # online-softmax attention chunk (train/prefill)
     flash_attention: bool = False  # Pallas flash kernel for train/prefill
                                    # (TPU target; interpret-mode on CPU)
@@ -309,6 +340,9 @@ class ModelConfig:
                 top_k=min(self.moe.top_k, 2),
                 n_shared=min(self.moe.n_shared, 1),
                 d_expert=min(self.moe.d_expert, 128),
+                d_dense=min(self.moe.d_dense, 512),
+                n_held=min(self.moe.n_held, 4),
+                held_offset=0,
             )
         if self.ssm:
             kw["ssm"] = replace(self.ssm, d_state=16, head_dim=32, chunk=16)
@@ -357,12 +391,11 @@ def param_count(cfg: ModelConfig) -> int:
                      + cfg.n_heads * m.v_head_dim * d)
     if cfg.moe is not None:
         e = cfg.moe
+        attn_only = per_layer
         per_layer += 3 * d * e.d_expert * (e.n_routed + e.n_shared)
         per_layer += d * e.n_routed  # router
-        if cfg.mla is None and cfg.family == "moe" and cfg.d_ff and not cfg.moe:
-            pass
-    elif cfg.family == "moe":
-        pass
+        # the leading dense layers: a SwiGLU of width d_dense for experts
+        n += e.first_k_dense * (attn_only + 3 * d * e.d_dense - per_layer)
     if cfg.family in ("ssm",):
         pass
     if cfg.xlstm is not None:
@@ -387,6 +420,32 @@ def active_param_count(cfg: ModelConfig) -> int:
     if cfg.moe is None:
         return total
     e = cfg.moe
-    all_expert = 3 * cfg.d_model * e.d_expert * (e.n_routed + e.n_shared) * cfg.n_layers
-    act_expert = 3 * cfg.d_model * e.d_expert * (e.top_k + e.n_shared) * cfg.n_layers
+    moe_layers = cfg.n_layers - e.first_k_dense
+    all_expert = 3 * cfg.d_model * e.d_expert * (e.n_routed + e.n_shared) * moe_layers
+    act_expert = 3 * cfg.d_model * e.d_expert * (e.top_k + e.n_shared) * moe_layers
     return int(total - all_expert + act_expert)
+
+
+def cut(cfg: ModelConfig, *, n_layers: Optional[int] = None,
+        vocab_size: Optional[int] = None, n_held: Optional[int] = None,
+        held_offset: int = 0) -> ModelConfig:
+    """A published configuration cut to one chip's share of a deployment:
+    fewer layers (the leading dense ones kept), an id range of the
+    vocabulary, and, of a mixture of experts, the ``n_held`` experts from
+    ``held_offset`` on (``MoEConfig.n_held``), the router still scoring
+    every one. Every width stays published. The launcher's ``--layers``,
+    ``--vocab``, ``--experts-held`` and ``--held-offset`` and the
+    benchmark's configuration modules cut through this one function."""
+    kw = {}
+    if n_layers is not None:
+        kw["n_layers"] = n_layers
+    if vocab_size is not None:
+        kw["vocab_size"] = vocab_size
+    if n_held is not None:
+        e = cfg.moe
+        if e is None or not 0 <= held_offset <= e.n_routed - n_held \
+                or n_held < 1:
+            raise ValueError(f"cannot hold experts [{held_offset}, "
+                             f"{held_offset + (n_held or 0)}) of {cfg.arch_id}")
+        kw["moe"] = replace(e, n_held=n_held, held_offset=held_offset)
+    return replace(cfg, **kw)

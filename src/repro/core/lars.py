@@ -6,8 +6,17 @@ LARS per tensor w with gradient g:
     trust = η · ||w|| / (||g|| + wd·||w|| + ε)
     v    ← μ·v + lr·trust·(g + wd·w)
     w    ← w − v
-1-D tensors (biases, norm scales) and the classifier head are excluded from
-trust scaling, as in the paper/MLPerf reference.
+1-D tensors (biases, norm scales) are excluded from trust scaling, as in
+the paper/MLPerf reference.
+
+A tensor here is a *published* tensor: an array that stacks several along
+its leading axes (a transformer's layer stack, a bank of experts) is
+given with ``stack``, per parameter the count of those axes (0 for every
+ResNet leaf, 1 for a layer stack or a bank of (layer, expert) matrices).
+Norms and trust ratios are then taken over the trailing axes, one per
+layer's tensor and per expert's matrix, and a published tensor of rank
+<= 1 (a layer's RMSNorm scale) takes trust 1. Without ``stack`` every
+array is one tensor. LAMB's ratio follows the same rule.
 
 Per-tensor norms are computed either the plain-jnp way or via the
 ``batched_norm`` Pallas kernel (paper §III-B.2) over the bucket-packed
@@ -52,29 +61,54 @@ def init_momentum(params, kind: str = "lars"):
     return zeros()
 
 
-def _is_scaled(p) -> bool:
-    """Trust-ratio scaling applies to >=2-D tensors only."""
-    return p.ndim >= 2
+def _is_scaled(p, stack: int = 0) -> bool:
+    """Trust-ratio scaling applies to published tensors of >= 2 dims."""
+    return p.ndim - stack >= 2
 
 
-def tensor_norms(tree):
+def unit_norms(x, stack: int = 0):
+    """L2 norm of each published tensor of ``x``: a scalar where ``stack``
+    is 0, else one per tensor along the ``stack`` leading axes, shaped to
+    broadcast against ``x``."""
+    sq = jnp.square(x.astype(jnp.float32))
+    if not stack:
+        return jnp.sqrt(jnp.sum(sq))
+    return jnp.sqrt(jnp.sum(sq, axis=tuple(range(stack, x.ndim)),
+                            keepdims=True))
+
+
+def tensor_norms(tree, stack):
     """Per-tensor L2 norms, plain jnp (the per-layer baseline the paper's
-    batched kernel replaces)."""
-    return jax.tree.map(
-        lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), tree)
+    batched kernel replaces); per published tensor under ``stack``."""
+    return jax.tree.map(unit_norms, tree, stack)
 
 
-def _batched_norms(params, grads, cfg):
+def check_unstacked(stack, what: str) -> None:
+    """Raise where ``stack`` (a model's ``stack_axes``, or None) stacks
+    published tensors: ``what`` packs each array whole and would take one
+    ratio per array, not the published rule."""
+    if stack is not None and any(jax.tree.leaves(stack)):
+        raise NotImplementedError(
+            f"{what} takes one LARS ratio per packed array; this model "
+            f"asks for one per published tensor of its stacks "
+            f"(lars_per_published_tensor): use the replicated update")
+
+
+def _batched_norms(params, grads, cfg, stack):
     """All per-tensor norms in one pass (kernel or packed-jnp path)."""
     if cfg.use_kernel:
+        check_unstacked(stack, "the batched-norm kernel (use_kernel)")
         from repro.kernels import ops
         return (ops.tree_norms(params), ops.tree_norms(grads))
-    return tensor_norms(params), tensor_norms(grads)
+    return tensor_norms(params, stack), tensor_norms(grads, stack)
 
 
-def update(params, grads, mom, lr, cfg: OptConfig):
+def update(params, grads, mom, lr, cfg: OptConfig, stack=None):
     """One optimizer step (all fp32; caller owns mixed-precision casts).
-    Returns (new_params, new_mom)."""
+    ``stack``: per parameter its count of stacking axes (module
+    docstring), or None. Returns (new_params, new_mom)."""
+    if stack is None:
+        stack = jax.tree.map(lambda _: 0, params)
     if cfg.kind == "sgdm":
         def upd(p, g, v):
             g = g.astype(jnp.float32) + cfg.weight_decay * p
@@ -83,11 +117,11 @@ def update(params, grads, mom, lr, cfg: OptConfig):
             return p - step, v2
         out = jax.tree.map(upd, params, grads, mom)
     elif cfg.kind == "lars":
-        wn, gn = _batched_norms(params, grads, cfg)
+        wn, gn = _batched_norms(params, grads, cfg, stack)
 
-        def upd(p, g, v, pw, gw):
+        def upd(p, g, v, pw, gw, k):
             g = g.astype(jnp.float32)
-            if _is_scaled(p):
+            if _is_scaled(p, k):
                 trust = cfg.trust_coef * pw / (gw + cfg.weight_decay * pw
                                                + cfg.eps)
                 trust = jnp.where(pw > 0, trust, 1.0)
@@ -96,7 +130,7 @@ def update(params, grads, mom, lr, cfg: OptConfig):
             g = g + cfg.weight_decay * p
             v2 = cfg.momentum * v + (lr * trust) * g
             return p - v2, v2
-        out = jax.tree.map(upd, params, grads, mom, wn, gn)
+        out = jax.tree.map(upd, params, grads, mom, wn, gn, stack)
     elif cfg.kind == "lamb":
         # You et al. 2020 (LAMB): Adam statistics + per-tensor trust ratio
         # ||w|| / ||update||. The paper's LARS lineage, known to work
@@ -118,18 +152,17 @@ def update(params, grads, mom, lr, cfg: OptConfig):
         c1 = 1 - b1 ** t.astype(jnp.float32)
         c2 = 1 - b2 ** t.astype(jnp.float32)
 
-        def upd(p, m, v):
+        def upd(p, m, v, k):
             u = (m / c1) / (jnp.sqrt(v / c2) + cfg.eps)
             u = u + cfg.weight_decay * p
-            if _is_scaled(p):
-                wn = jnp.sqrt(jnp.sum(jnp.square(p)))
-                un = jnp.sqrt(jnp.sum(jnp.square(u)))
+            if _is_scaled(p, k):
+                wn, un = unit_norms(p, k), unit_norms(u, k)
                 ratio = jnp.where((wn > 0) & (un > 0), wn / un, 1.0)
             else:
                 ratio = 1.0
             return p - lr * ratio * u
 
-        new_params = jax.tree.map(upd, params, new_m, new_v)
+        new_params = jax.tree.map(upd, params, new_m, new_v, stack)
         return new_params, {"m": new_m, "v": new_v, "count": t}
     else:
         raise ValueError(cfg.kind)

@@ -5,6 +5,9 @@ Examples:
       --reduced --seq 128 --batch 8 --steps 100 --optimizer lars --lr 1.0
   PYTHONPATH=src python -m repro.launch.train --arch resnet50 --reduced \\
       --batch 32 --steps 200 --comm bucketed --warmup 20
+  PYTHONPATH=src python -m repro.launch.train --arch deepseek-v2-lite \\
+      --layers 5 --vocab 12800 --experts-held 8 --seq 8192 --batch 4 \\
+      --data uniform --smoothing 0 --steps 20
 
 Observability (docs/observability.md): ``--metrics out.jsonl`` mirrors the
 tag stream to a JSONL artifact; ``--trace DIR`` runs the training loop
@@ -23,6 +26,7 @@ import os
 import jax
 
 from repro.configs import get_config
+from repro.configs.base import cut
 from repro.configs.shapes import InputShape
 from repro.core import lars
 from repro.core.schedule import ScheduleConfig, linear_scaled_lr, \
@@ -39,12 +43,35 @@ from repro.train.step import make_eval_step, make_train_step
 WHERE = "repro/launch/train.py"
 
 
+#: the program's random streams take a seed below this (int32)
+SEED_MODULUS = 2 ** 31 - 1
+
+
+def seed_arg(text: str) -> int:
+    """``--seed``: any whole number, folded into the seeds the program's
+    random streams take (``seed mod SEED_MODULUS``); a seed below the
+    modulus is kept as it is."""
+    return int(text) % SEED_MODULUS
+
+
 def main(argv=None):
     """Parse ``argv`` and train. Returns (final state, history)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
                     help="smoke-sized variant of the same family")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to its first N layers (the leading "
+                         "dense ones kept; configs.base.cut)")
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="cut the vocabulary to its first V ids")
+    ap.add_argument("--experts-held", type=int, default=None, metavar="N",
+                    help="hold N of a MoE model's routed experts, one "
+                         "chip's share under expert parallelism: the "
+                         "router scores all of them, the held ones run "
+                         "dropless (MoEConfig.n_held)")
+    ap.add_argument("--held-offset", type=int, default=0, metavar="K",
+                    help="the first held expert (with --experts-held)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -100,7 +127,8 @@ def main(argv=None):
     ap.add_argument("--smoothing", type=float, default=0.1)
     ap.add_argument("--momentum", type=float, default=0.9)
     ap.add_argument("--weight-decay", type=float, default=5e-5)
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=seed_arg, default=0,
+                    help="any whole number; folded below 2^31 - 1")
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--devices", type=int, default=None, metavar="N",
                     help="build the mesh over the first N devices "
@@ -174,6 +202,8 @@ def _run(args, *, reg: obs_metrics.Registry):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    cfg = cut(cfg, n_layers=args.layers, vocab_size=args.vocab,
+              n_held=args.experts_held, held_offset=args.held_offset)
     mesh = make_local_mesh(args.model_parallel, devices=args.devices)
     model = build_model(cfg)
 

@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.compat import shard_map
 from repro.models.common import PD, constrain, dense_pd, dp_axes, \
-    rms_norm, rope
+    rms_norm, rope, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -114,7 +114,8 @@ def chunked_attention(q, k, v, *, q_offset, causal: bool, window: int = 0,
     nq, nk = Sq // cq, Sk // ck
 
     qr = q.reshape(B, nq, cq, K, G, Dh)
-    # (nk, B, ck, K, Dh) so a static slice over axis 0 selects visible blocks
+    # (nk, B, ck, K, Dh): the scan over a query chunk's visible key blocks
+    # reads block j in place
     kr = jnp.moveaxis(k.reshape(B, nk, ck, K, Dh), 1, 0)
     vr = jnp.moveaxis(v.reshape(B, nk, ck, K, Dv), 1, 0)
 
@@ -143,9 +144,10 @@ def chunked_attention(q, k, v, *, q_offset, causal: bool, window: int = 0,
         full_lo = min(full_lo, full_hi)
 
         def body(masked):
-            def run(carry, xs):
+            def run(carry, j):
                 m, l, acc = carry
-                kj, vj, j = xs
+                kj = jax.lax.dynamic_index_in_dim(kr, j, keepdims=False)
+                vj = jax.lax.dynamic_index_in_dim(vr, j, keepdims=False)
                 s = jnp.einsum("bqkgd,bckd->bkgqc", qi, kj,
                                preferred_element_type=jnp.float32)
                 if masked:
@@ -176,8 +178,10 @@ def chunked_attention(q, k, v, *, q_offset, causal: bool, window: int = 0,
             if mhi <= mlo:
                 continue
             js = jnp.arange(mlo, mhi)
-            carry, _ = jax.lax.scan(body(masked), carry,
-                                    (kr[mlo:mhi], vr[mlo:mhi], js))
+            # each key block's scores are recomputed in the backward pass
+            # (flash attention's trade): the scan keeps its carries, never
+            # a (cq, ck) probability block per step
+            carry, _ = jax.lax.scan(jax.checkpoint(body(masked)), carry, js)
         m, l, acc = carry
         oi = acc / jnp.maximum(l, 1e-30)[..., None]
         outs.append(jnp.moveaxis(oi, 3, 1))        # (B,cq,K,G,Dh)
@@ -305,7 +309,7 @@ def mla_decode_inplace(p, x, pos, cfg, ckv_all, kr_all, layer):
     ckv_new = rms_norm(kv_a[..., :m.kv_lora_rank], p["ckv_norm"], cfg.rms_eps)
     kr_new = kv_a[..., m.kv_lora_rank:].reshape(B, 1, 1, m.rope_head_dim)
     pp = jnp.full((B, 1), pos, jnp.int32)
-    kr_new = rope(kr_new, pp, cfg.rope_theta)
+    kr_new = rope(kr_new, pp, cfg.rope_theta, cfg.rope_yarn)
     ckv_all = jax.lax.dynamic_update_slice(ckv_all, ckv_new[None],
                                            (layer, 0, pos, 0))
     kr_all = jax.lax.dynamic_update_slice(kr_all, kr_new[:, :, 0][None],
@@ -314,11 +318,11 @@ def mla_decode_inplace(p, x, pos, cfg, ckv_all, kr_all, layer):
     kr_c = jax.lax.dynamic_index_in_dim(kr_all, layer, 0, keepdims=False)
     q = (x @ p["wq"]).reshape(B, 1, H, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = rope(q_rope, pp, cfg.rope_theta)
+    q_rope = rope(q_rope, pp, cfg.rope_theta, cfg.rope_yarn)
     wk_b = p["wk_b"].reshape(m.kv_lora_rank, H, m.nope_head_dim)
     q_abs = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0].astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     s = (jnp.einsum("bhr,bsr->bhs", q_abs.astype(ckv_c.dtype), ckv_c,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0], kr_c,
@@ -375,21 +379,51 @@ def gqa_decode(p, x, pos, cfg, cache, *, cross: bool = False):
 # MLA block (DeepSeek-V2)
 
 
+def mla_softmax_scale(cfg) -> float:
+    """(qk_nope + qk_rope)^-1/2, times m(factor, mscale_all_dim)^2 under
+    YaRN: DeepSeek-V2's 192^-1/2 * 1.5897."""
+    m = cfg.mla
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    y = cfg.rope_yarn
+    if y is not None:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_parallel(p, x, positions, cfg, *, cache_len: int = 0, mesh=None):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1),
+    train/prefill form, under the ``attention`` scope. Queries are full
+    rank (``wq``: d -> H * (nope + rope)); keys and values come from the
+    ``kv_lora_rank`` latent (RMS-normed) through ``wk_b`` and ``wv_b``; one
+    rope key of ``rope_head_dim`` is shared by every head. The rope is the
+    rotate-half form (``common.rope``, YaRN's frequencies under
+    ``cfg.rope_yarn``): DeepSeek's interleaved layout only permutes the
+    rope columns of ``wq`` and ``wkv_a``. The softmax scale is
+    ``mla_softmax_scale``; the queries carry its factor over the chunked
+    attention's own head_dim^-1/2."""
+    with jax.named_scope("attention"):
+        return _mla_parallel(p, x, positions, cfg, cache_len=cache_len,
+                             mesh=mesh)
+
+
+def _mla_parallel(p, x, positions, cfg, *, cache_len: int, mesh):
     m = cfg.mla
     B, S = x.shape[:2]
     H = cfg.n_heads
     kv_a = x @ p["wkv_a"]
     ckv = rms_norm(kv_a[..., :m.kv_lora_rank], p["ckv_norm"], cfg.rms_eps)
     krope = kv_a[..., m.kv_lora_rank:].reshape(B, S, 1, m.rope_head_dim)
-    krope = rope(krope, positions, cfg.rope_theta)
+    krope = rope(krope, positions, cfg.rope_theta, cfg.rope_yarn)
     q = (x @ p["wq"]).reshape(B, S, H, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_yarn)
     k_nope = (ckv @ p["wk_b"]).reshape(B, S, H, m.nope_head_dim)
     v = (ckv @ p["wv_b"]).reshape(B, S, H, m.v_head_dim)
     # fold the shared rope key in as extra head dims (standard MLA trick)
     q_eff = jnp.concatenate([q_nope, q_rope], axis=-1)
+    gain = mla_softmax_scale(cfg) * (m.nope_head_dim + m.rope_head_dim) ** 0.5
+    if gain != 1.0:
+        q_eff = q_eff * jnp.asarray(gain, q_eff.dtype)
     k_eff = jnp.concatenate(
         [k_nope, jnp.broadcast_to(krope, (B, S, H, m.rope_head_dim))], axis=-1)
     if mesh is not None:
@@ -420,18 +454,18 @@ def mla_decode(p, x, pos, cfg, cache):
     ckv_new = rms_norm(kv_a[..., :m.kv_lora_rank], p["ckv_norm"], cfg.rms_eps)
     kr_new = kv_a[..., m.kv_lora_rank:].reshape(B, 1, 1, m.rope_head_dim)
     pp = jnp.full((B, 1), pos, jnp.int32)
-    kr_new = rope(kr_new, pp, cfg.rope_theta)
+    kr_new = rope(kr_new, pp, cfg.rope_theta, cfg.rope_yarn)
     ckv_c = jax.lax.dynamic_update_slice(cache["ckv"], ckv_new, (0, pos, 0))
     kr_c = jax.lax.dynamic_update_slice(cache["krope"], kr_new[:, :, 0],
                                         (0, pos, 0))
     q = (x @ p["wq"]).reshape(B, 1, H, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = rope(q_rope, pp, cfg.rope_theta)
+    q_rope = rope(q_rope, pp, cfg.rope_theta, cfg.rope_yarn)
     wk_b = p["wk_b"].reshape(m.kv_lora_rank, H, m.nope_head_dim)
     # absorb W^UK into q:   q̃ = q_nope · W^UK   (B,H,r)
     q_abs = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0].astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     s = (jnp.einsum("bhr,bsr->bhs", q_abs.astype(ckv_c.dtype), ckv_c,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0], kr_c,

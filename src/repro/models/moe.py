@@ -13,6 +13,13 @@ explicit in the lowered HLO (it is a roofline term we track):
   sequence. Tokens are replicated over ``model``; every device evaluates
   only its local expert shard for all tokens and a ``psum`` combines.
 
+* held experts (``MoEConfig.n_held`` > 0, ``moe_held``): one chip's
+  share of expert parallelism. The chip holds experts ``[held_offset,
+  held_offset + n_held)`` and computes their part of the result for every
+  token routed to them, dropless: the routed slots sorted by expert, one
+  grouped product per weight over the held experts, a gate-weighted
+  scatter-add back. What the other chips' experts add is not computed.
+
 Shared ("always-on") experts are a plain dense SwiGLU of width
 ``n_shared * d_expert`` applied outside this module (tensor-parallel).
 """
@@ -26,6 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.compat import shard_map
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.models.common import PD, dense_pd
 
 
@@ -64,16 +72,42 @@ def _dp_axes(mesh):
     return tuple(a for a in mesh.axis_names if a != "model")
 
 
-def _route(x_flat, router_w, e):
-    """Top-k routing. Returns gates (T,k) f32, ids (T,k) i32, aux-loss."""
+def _gates(x_flat, router_w, e):
+    """Top-k routing over all ``n_routed`` experts: float32 logits, a
+    softmax, the top k; the gates renormalised to sum to 1 where
+    ``e.norm_topk``, then times ``e.routed_scale``. Returns gates (T,k)
+    f32, ids (T,k) i32 and the probabilities (T,E)."""
     logits = (x_flat.astype(jnp.float32) @ router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, e.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    # Switch-style load-balance loss: E * sum_e f_e * p_e
-    f = jnp.zeros(e.n_routed).at[ids.reshape(-1)].add(1.0) / ids.size
-    p = probs.mean(0)
-    aux = e.n_routed * jnp.sum(f * p)
+    if e.norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if e.routed_scale != 1.0:
+        gates = gates * e.routed_scale
+    return gates, ids, probs
+
+
+def _balance(counts, prob_sums, tokens: int, e):
+    """The balance loss E * sum_e f_e * P_e (Switch; DeepSeek-V2's
+    expert-level loss), f_e the share of the routed slots that chose
+    expert e and P_e its mean probability, from per-sequence ``counts``
+    and ``prob_sums`` (n, E) over ``tokens`` tokens a sequence: taken per
+    sequence and averaged with ``e.seq_aux``, else over all of them."""
+    if not e.seq_aux:
+        tokens = tokens * counts.shape[0]
+        counts = counts.sum(0, keepdims=True)
+        prob_sums = prob_sums.sum(0, keepdims=True)
+    f = counts / (tokens * e.top_k)
+    p = prob_sums / tokens
+    return e.n_routed * jnp.mean(jnp.sum(f * p, axis=-1))
+
+
+def _route(x_flat, router_w, e):
+    """Top-k routing (``_gates``). Returns gates (T,k) f32, ids (T,k) i32,
+    aux-loss."""
+    gates, ids, probs = _gates(x_flat, router_w, e)
+    counts = jnp.zeros(e.n_routed).at[ids.reshape(-1)].add(1.0)
+    aux = _balance(counts[None], probs.sum(0)[None], x_flat.shape[0], e)
     return gates, ids, aux
 
 
@@ -170,3 +204,102 @@ def _moe_local_psum(x, router_w, w_gate, w_up, w_down, *, cfg, tp, dp):
     # tokens are replicated over 'model' here: aux only varies over dp
     aux = jax.lax.pmean(aux, dp) if dp else aux
     return out.reshape(B, S, d).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# held experts, dropless (one chip's share of expert parallelism)
+
+def _live_rows(x, live):
+    """``x`` with the rows past the held experts' groups set to 0; its
+    gradient likewise (a select, so no value in those rows reaches the
+    live ones, NaN included)."""
+    return jnp.where(live, x, jnp.zeros((), x.dtype))
+
+
+#: the counters ``moe_held`` returns, per step as the train step reports
+#: them (``obs.moe.rows_here``, ``obs.moe.load_max_over_mean``)
+HELD_STATS = ("moe_rows_here", "moe_load_max_over_mean")
+
+
+def held_pd(cfg, n_layers: int):
+    """The held experts of ``n_layers`` MoE layers: one (d, d_expert) or
+    (d_expert, d) matrix per (layer, expert), stacked layer-major along
+    one leading axis, (n_layers * n_held, ...). Each is a published
+    tensor, so LARS scales each on its own (``PD.stack`` 1)."""
+    d, e = cfg.d_model, cfg.moe
+    n = n_layers * e.n_held
+    scale_in = d ** -0.5
+    scale_out = e.d_expert ** -0.5 / math.sqrt(2 * cfg.n_layers)
+    return {
+        "w_gate": PD((n, d, e.d_expert), spec=P(None, None, None),
+                     scale=scale_in, stack=1),
+        "w_up": PD((n, d, e.d_expert), spec=P(None, None, None),
+                   scale=scale_in, stack=1),
+        "w_down": PD((n, e.d_expert, d), spec=P(None, None, None),
+                     scale=scale_out, stack=1),
+    }
+
+
+def moe_held(router_w, experts, x, cfg):
+    """The held experts' part of a MoE layer, dropless. ``router_w`` (d,
+    n_routed); ``experts`` {w_gate, w_up (n_held, d, f), w_down (n_held, f,
+    d)}; x (B, S, d). Returns (out (B, S, d), balance loss, {``HELD_STATS``}).
+
+    One sequence at a time (a scan over B), so that the static buffers
+    hold one sequence's worst case and not the batch's: the router scores
+    all ``n_routed`` experts (``_gates``); of the S * k routed slots, those
+    of held experts are sorted by expert id to the front of a buffer of
+    all S * k (the worst case: every slot held), the rest after them;
+    ``kernels.grouped_matmul`` runs each weight's grouped product over the
+    held experts' rows alone (its time follows those rows, not the
+    buffer), and each row comes back to its token scaled by its gate (a
+    scatter-add). Under the ``router``, ``dispatch``,
+    ``experts`` and ``combine`` scopes. Each sequence is rematerialised
+    in the backward pass.
+
+    A grouped product leaves the rows past its groups as it finds them,
+    in its result and in its data gradient alike; its weight gradient
+    reads the groups' rows alone. So the gathered rows and the last
+    product's result are masked to the live rows (``_live_rows``): the mask keeps
+    those rows out of the result, and the gathered rows' mask keeps the
+    data gradient's out of the tokens' gradient."""
+    e = cfg.moe
+    S, k = x.shape[1], e.top_k
+
+    def sequence(_, xs):
+        with jax.named_scope("router"):
+            gates, ids, probs = _gates(xs, router_w, e)
+        with jax.named_scope("dispatch"):
+            local = ids.reshape(-1) - e.held_offset           # (S*k,)
+            held = (local >= 0) & (local < e.n_held)
+            key = jnp.where(held, local, e.n_held)
+            order = jnp.argsort(key, stable=True)
+            tok = order // k
+            counts = jnp.zeros(e.n_held + 1, jnp.int32).at[key].add(1)
+            n_here = S * k - counts[e.n_held]
+            live = (jnp.arange(S * k) < n_here)[:, None]
+            rows = _live_rows(xs[tok], live)                   # (S*k, d)
+            sizes = counts[:e.n_held]
+        with jax.named_scope("experts"):
+            h = jax.nn.silu(grouped_matmul(rows, experts["w_gate"], sizes))
+            h = h * grouped_matmul(rows, experts["w_up"], sizes)
+            y = grouped_matmul(h, experts["w_down"], sizes)
+        with jax.named_scope("combine"):
+            g = jnp.where(held, gates.reshape(-1), 0.0)[order]
+            y = _live_rows(y.astype(jnp.float32), live) * g[:, None]
+            out = jnp.zeros(xs.shape, jnp.float32).at[tok].add(y)
+        with jax.named_scope("router"):
+            chose = jnp.zeros(e.n_routed).at[ids.reshape(-1)].add(1.0)
+        return None, (out.astype(xs.dtype), chose, probs.sum(0), sizes)
+
+    # each sequence is recomputed in the backward pass, so that no buffer
+    # of the batch's slots is held between the passes
+    _, (out, chose, prob_sums, sizes) = jax.lax.scan(
+        jax.checkpoint(sequence), None, x)
+    aux = _balance(chose, prob_sums, S, e)
+    sizes = sizes.sum(0)
+    n_here = jnp.sum(sizes)
+    mean = jnp.maximum(n_here, 1) / e.n_held
+    stats = {"moe_rows_here": n_here.astype(jnp.float32),
+             "moe_load_max_over_mean": jnp.max(sizes) / mean}
+    return out, aux, stats

@@ -4,6 +4,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -24,8 +25,24 @@ class Model:
     decode_fn: Callable = None
     cache_pd_fn: Callable = None
 
-    def forward_train(self, params, batch, mesh=None, bn_state=None):
-        return self.train_fn(params, batch, mesh, bn_state)
+    def forward_train(self, params, batch, mesh=None, bn_state=None,
+                      counters: bool = False):
+        """((logits, aux), new batch-norm state); with ``counters``
+        ((logits, aux, counters), ...), the counters a dict of scalars
+        (``moe.HELD_STATS`` for held experts, else {})."""
+        out, new_bn = self.train_fn(params, batch, mesh, bn_state)
+        return (out if counters else out[:2]), new_bn
+
+    @property
+    def stack_axes(self):
+        """Per parameter, the leading axes along which it stacks published
+        tensors (``PD.stack``), for LARS's one ratio per published tensor;
+        None where the configuration takes one ratio per stored array
+        (``ModelConfig.lars_per_published_tensor``)."""
+        if not self.cfg.lars_per_published_tensor:
+            return None
+        return jax.tree.map(lambda pd: pd.stack, self.param_pd,
+                            is_leaf=lambda x: isinstance(x, PD))
 
     def forward_prefill(self, params, batch, cache_len, mesh=None):
         return self.prefill_fn(params, batch, cache_len, mesh)
@@ -45,7 +62,7 @@ def build_model(cfg: ModelConfig) -> Model:
             logits, new_state = rn.resnet_forward(
                 cast_to_compute(params), bn_state, cfg, batch["images"],
                 train=True, mesh=mesh)
-            return (logits, jnp.float32(0)), new_state
+            return (logits, jnp.float32(0), {}), new_state
 
         return Model(cfg=cfg, param_pd=params_pd, bn_state_pd=state_pd,
                      train_fn=train_fn)
@@ -53,9 +70,8 @@ def build_model(cfg: ModelConfig) -> Model:
     pd = tf.lm_pd(cfg)
 
     def train_fn(params, batch, mesh, bn_state=None):
-        logits, aux = tf.forward_train(cast_to_compute(params), cfg, mesh,
-                                       batch)
-        return (logits, aux), None
+        return tf.forward_train(cast_to_compute(params), cfg, mesh,
+                                batch), None
 
     def prefill_fn(params, batch, cache_len, mesh):
         return tf.forward_prefill(cast_to_compute(params), cfg, mesh, batch,

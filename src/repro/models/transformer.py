@@ -40,12 +40,18 @@ def _attn_pd(cfg):
     return attn.mla_pd(cfg) if cfg.mla is not None else attn.gqa_pd(cfg)
 
 
-def _dense_layer_pd(cfg):
+def _dense_layer_pd(cfg, dense: bool = False):
+    """One decoder layer; ``dense``: a MoE model's leading dense layer."""
     d = cfg.d_model
     p = {"ln1": PD((d,), init="ones"), "attn": _attn_pd(cfg),
          "ln2": PD((d,), init="ones")}
-    if cfg.moe is not None:
+    if dense:
+        p["mlp"] = mlpm.swiglu_pd(cfg, d_ff=cfg.moe.d_dense)
+    elif cfg.moe is not None:
         p["moe"] = moem.moe_pd(cfg)
+        if cfg.moe.n_held:
+            # the held experts live in ``experts``, outside the layer stack
+            p["moe"] = {"router": p["moe"]["router"]}
         if cfg.moe.n_shared:
             p["shared"] = mlpm.swiglu_pd(
                 cfg, d_ff=cfg.moe.n_shared * cfg.moe.d_expert)
@@ -79,7 +85,13 @@ def lm_pd(cfg) -> Dict[str, Any]:
 
     fam = cfg.family
     if fam in ("dense", "moe", "vlm"):
-        tree["layers"] = pd_stack(_dense_layer_pd(cfg), cfg.n_layers)
+        k = cfg.moe.first_k_dense if fam == "moe" else 0
+        if k:
+            tree["dense_layers"] = pd_stack(
+                _dense_layer_pd(cfg, dense=True), k)
+        tree["layers"] = pd_stack(_dense_layer_pd(cfg), cfg.n_layers - k)
+        if fam == "moe" and cfg.moe.n_held:
+            tree["experts"] = moem.held_pd(cfg, cfg.n_layers - k)
         if fam == "vlm":
             tree["proj"] = dense_pd(d, d, spec=P(None, None))  # stub projector
     elif fam == "ssm" and cfg.xlstm is not None:
@@ -148,8 +160,10 @@ def _scan_layers(body, x, layers, cfg, extra=None):
 
 
 def _dense_block(p, x, positions, cfg, mesh, *, decode=False, cache=None,
-                 pos=None, cache_len=0):
-    """One decoder layer. Returns (x, aux, new_cache)."""
+                 pos=None, cache_len=0, experts=None):
+    """One decoder layer: a dense FFN where ``p`` has ``mlp``, else a MoE
+    layer (``experts``: this layer's held experts, under ``n_held``).
+    Returns (x, aux, new_cache, MoE counters {})."""
     h = rms_norm(x, p["ln1"], cfg.rms_eps)
     new_cache = {}
     if cfg.mla is not None:
@@ -166,53 +180,107 @@ def _dense_block(p, x, positions, cfg, mesh, *, decode=False, cache=None,
                                              cache_len=cache_len, mesh=mesh)
     x = x + a
     h = rms_norm(x, p["ln2"], cfg.rms_eps)
-    aux = jnp.float32(0)
-    if cfg.moe is not None:
-        m, aux = moem.moe_apply(p["moe"], h, cfg, mesh, decode=decode)
-        if cfg.moe.n_shared:
-            m = m + mlpm.swiglu_apply(p["shared"], h)
-    else:
+    aux, stats = jnp.float32(0), {}
+    if "mlp" in p:
         m = mlpm.swiglu_apply(p["mlp"], h)
-    return x + m, aux, new_cache
+    else:
+        with jax.named_scope("moe"):
+            if experts is not None:
+                m, aux, stats = moem.moe_held(p["moe"]["router"], experts,
+                                              h, cfg)
+            else:
+                m, aux = moem.moe_apply(p["moe"], h, cfg, mesh,
+                                        decode=decode)
+            if cfg.moe.n_shared:
+                m = m + mlpm.swiglu_apply(p["shared"], h)
+    return x + m, aux, new_cache, stats
+
+
+def _held_view(params, cfg):
+    """The held experts as (MoE layers, n_held, ...) for the layer scan,
+    or None without held experts."""
+    if "experts" not in params:
+        return None
+    return jax.tree.map(
+        lambda a: a.reshape(-1, cfg.moe.n_held, *a.shape[1:]),
+        params["experts"])
+
+
+def _stacks(params, cfg):
+    """(layer stack, its held experts or None) in order: the leading
+    dense layers, then the rest."""
+    out = []
+    if "dense_layers" in params:
+        out.append(("dense_layers", params["dense_layers"], None))
+    out.append(("layers", params["layers"], _held_view(params, cfg)))
+    return out
 
 
 def _dense_forward(params, cfg, mesh, x, positions, *, mode, cache=None,
                    pos=None, cache_len=0):
-    """mode: train | prefill | decode. x: embedded inputs (B,S,d)."""
+    """mode: train | prefill | decode. x: embedded inputs (B,S,d).
+    Train returns (x, aux, MoE counters); prefill and decode (x, aux,
+    cache), the cache {stack name: its cache} where the model has
+    leading dense layers."""
     dp = dp_axes(mesh) if mesh is not None else ()
     x = constrain(x, mesh, P(dp, None, None))
 
     if mode == "train":
-        def body(x, layer_p):
-            x, aux, _ = _dense_block(layer_p, x, positions, cfg, mesh)
-            return x, aux
-        return _scan_layers(body, x, params["layers"], cfg)
+        aux, stats = jnp.float32(0), {}
+        for _, layers, held in _stacks(params, cfg):
+            def f(carry, xs):
+                x, aux, stats = carry
+                layer_p, ex = xs
+                x, a, _, st = _dense_block(layer_p, x, positions, cfg, mesh,
+                                           experts=ex)
+                if st:
+                    # rows add up over the layers; the load is the worst
+                    st = {"moe_rows_here": stats["moe_rows_here"]
+                          + st["moe_rows_here"],
+                          "moe_load_max_over_mean": jnp.maximum(
+                              stats["moe_load_max_over_mean"],
+                              st["moe_load_max_over_mean"])}
+                return (x, aux + a, st), None
 
-    if mode == "prefill":
-        def f(carry, layer_p):
+            st0 = ({k: jnp.float32(0) for k in moem.HELD_STATS}
+                   if held is not None else {})
+            (x, aux, st), _ = jax.lax.scan(_maybe_remat(f, cfg),
+                                           (x, aux, st0), (layers, held))
+            stats.update(st)
+        return x, aux, stats
+
+    stacks = _stacks(params, cfg)
+    caches = {}
+    aux = jnp.float32(0)
+    for name, layers, held in stacks:
+        if mode == "prefill":
+            def f(carry, xs):
+                x, aux = carry
+                layer_p, ex = xs
+                x, a, c, _ = _dense_block(layer_p, x, positions, cfg, mesh,
+                                          cache_len=cache_len, experts=ex)
+                return (x, aux + a), c
+            (x, aux), caches[name] = jax.lax.scan(f, (x, aux),
+                                                  (layers, held))
+            continue
+        # decode: cache scanned through xs/ys. Two alternatives were
+        # measured and REFUTED (§Perf-2): a fully-unrolled in-place loop
+        # (XLA materialized per-layer full-cache copies: 0.10s -> 11.0s
+        # memory term) and cache-as-scan-carry (loop double-buffering
+        # copies the whole cache every iteration: -> 0.94s). XLA's xs/ys
+        # loop aliasing is already the best layout for a layer-scanned
+        # cache.
+        def f(carry, xs):
             x, aux = carry
-            x, a, c = _dense_block(layer_p, x, positions, cfg, mesh,
-                                   cache_len=cache_len)
-            return (x, aux + a), c
-        (x, aux), cache = jax.lax.scan(f, (x, jnp.float32(0)),
-                                       params["layers"])
-        return x, aux, cache
-
-    # decode: cache scanned through xs/ys. Two alternatives were measured
-    # and REFUTED (§Perf-2): a fully-unrolled in-place loop (XLA
-    # materialized per-layer full-cache copies: 0.10s -> 11.0s memory term)
-    # and cache-as-scan-carry (loop double-buffering copies the whole cache
-    # every iteration: -> 0.94s). XLA's xs/ys loop aliasing is already the
-    # best layout for a layer-scanned cache.
-    def f(carry, xs):
-        x, aux = carry
-        layer_p, c = xs
-        x, a, c2 = _dense_block(layer_p, x, positions, cfg, mesh,
-                                decode=True, cache=c, pos=pos)
-        return (x, aux + a), c2
-    (x, aux), cache = jax.lax.scan(f, (x, jnp.float32(0)),
-                                   (params["layers"], cache))
-    return x, aux, cache
+            layer_p, ex, c = xs
+            x, a, c2, _ = _dense_block(layer_p, x, positions, cfg, mesh,
+                                       decode=True, cache=c, pos=pos,
+                                       experts=ex)
+            return (x, aux + a), c2
+        layer_cache = cache[name] if len(stacks) > 1 else cache
+        (x, aux), caches[name] = jax.lax.scan(f, (x, aux),
+                                              (layers, held, layer_cache))
+    return x, aux, caches if len(stacks) > 1 else caches["layers"]
 
 
 # ---------------------------------------------------------------------------
@@ -406,12 +474,15 @@ def _whisper_forward(params, cfg, mesh, tokens, frames, *, mode, cache=None,
 
 
 def forward_train(params, cfg, mesh, batch):
-    """batch: {'tokens': (B,S)[, 'frames': (B,P,d)]}. Returns (logits, aux)."""
+    """batch: {'tokens': (B,S)[, 'frames': (B,P,d)]}. Returns (logits, aux,
+    counters): the counters are the held experts' (``moe.HELD_STATS``),
+    {} for every other model."""
     tokens = batch["tokens"]
+    stats = {}
     if cfg.family == "audio":
         x, aux, _ = _whisper_forward(params, cfg, mesh, tokens,
                                      batch["frames"], mode="train")
-        return _logits(params, cfg, x), aux
+        return _logits(params, cfg, x), aux, stats
     x = _embed(params, cfg, tokens)
     if cfg.family == "vlm":
         pre = (batch["frames"].astype(jnp.bfloat16)
@@ -420,13 +491,14 @@ def forward_train(params, cfg, mesh, batch):
     S = x.shape[1]
     positions = jnp.arange(S)[None]
     if cfg.family in ("dense", "moe", "vlm"):
-        x, aux = _dense_forward(params, cfg, mesh, x, positions, mode="train")
+        x, aux, stats = _dense_forward(params, cfg, mesh, x, positions,
+                                       mode="train")
     elif cfg.family == "hybrid":
         x, aux, _ = _hybrid_forward(params, cfg, mesh, x, positions,
                                     mode="train")
     else:
         x, aux, _ = _xlstm_forward(params, cfg, mesh, x, mode="train")
-    return _logits(params, cfg, x), aux
+    return _logits(params, cfg, x), aux, stats
 
 
 def forward_prefill(params, cfg, mesh, batch, cache_len: int):
@@ -502,6 +574,10 @@ def cache_pd(cfg, batch: int, max_seq: int, dp=("data",)):
               "krope": PD((batch, max_seq, m.rope_head_dim),
                           spec=P(dp, None, None), init="zeros",
                           dtype=jnp.bfloat16)}
+        k = cfg.moe.first_k_dense if cfg.moe is not None else 0
+        if k:
+            return {"dense_layers": pd_stack(pd, k),
+                    "layers": pd_stack(pd, cfg.n_layers - k)}
         return pd_stack(pd, cfg.n_layers)
     if fam == "hybrid":
         n_groups = len(_hybrid_groups(cfg))

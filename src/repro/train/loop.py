@@ -218,6 +218,10 @@ def train(state: TrainState, train_step: Callable, batch_fn: Callable, *,
             if guarded:
                 obs_metrics.gauge("obs.guard.gnorm", m["gnorm"],
                                   where=_WHERE, step=step)
+            for k in ("moe_rows_here", "moe_load_max_over_mean"):
+                if k in m:     # a held-expert model's counters (models/moe.py)
+                    obs_metrics.gauge("obs.moe." + k[4:], m[k],
+                                      where=_WHERE, step=step)
 
     def emit_loop_counters(step: int) -> None:
         # StdoutSink prints every event: counters go out on logged steps

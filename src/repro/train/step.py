@@ -55,24 +55,38 @@ def _lm_loss(logits, labels, *, smoothing):
 
 def make_loss_fn(model, *, smoothing: float = 0.1, aux_coef: float = 0.01,
                  mesh=None):
+    """The training loss: label-smoothed cross entropy plus the MoE balance
+    loss times ``cfg.moe.router_aux_coef`` (``aux_coef`` for a model
+    without experts, whose balance loss is 0). Its metrics hold ``loss``,
+    ``aux``, ``acc`` and the model's counters (``held_stat_keys``)."""
     cfg = model.cfg
+    if cfg.moe is not None:
+        aux_coef = cfg.moe.router_aux_coef
 
     def loss_fn(params, batch, bn_state=None):
         # every op of the forward carries the ``forward`` scope in its HLO
         # ``op_name``; differentiating it puts the backward's ops under
         # ``transpose(jvp(forward))`` (docs/observability.md)
         with jax.named_scope("forward"):
-            (logits, aux), new_bn = model.forward_train(params, batch, mesh,
-                                                        bn_state)
+            (logits, aux, counters), new_bn = model.forward_train(
+                params, batch, mesh, bn_state, counters=True)
             loss, n = _lm_loss(logits, batch["labels"], smoothing=smoothing)
             total = loss + aux_coef * aux
             acc = top1_accuracy(logits, batch["labels"]
                                 if logits.shape[:-1] == batch["labels"].shape
                                 else jnp.full(logits.shape[:-1], IGNORE))
-            metrics = {"loss": loss, "aux": aux, "acc": acc}
+            metrics = {"loss": loss, "aux": aux, "acc": acc, **counters}
         return total, (metrics, new_bn)
 
     return loss_fn
+
+
+def held_stat_keys(model) -> tuple:
+    """The counters a model's train step reports beside its loss: the held
+    experts' (``models.moe.HELD_STATS``), or none."""
+    from repro.models.moe import HELD_STATS
+    e = model.cfg.moe
+    return HELD_STATS if e is not None and e.n_held else ()
 
 
 def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
@@ -147,7 +161,7 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
             if guard_in is not None:
                 lr = lr * guard_in["lr_scale"]
             params, mom = lars.update(state.params, grads, state.mom, lr,
-                                      opt_cfg)
+                                      opt_cfg, stack=model.stack_axes)
             metrics = dict(metrics, lr=lr)
             new_state = TrainState(state.step + 1, params, mom, new_bn)
             if guard_in is None:
@@ -226,6 +240,7 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
     if shard_update:
         assert opt_cfg.kind in ("lars", "sgdm") and not opt_cfg.nesterov, \
             f"sharding={sharding!r} supports lars/sgdm, not {opt_cfg.kind!r}"
+        lars.check_unstacked(model.stack_axes, f"sharding={sharding!r}")
 
     profile = None
     if (bucket_mb == "auto" and comm != "naive"
@@ -485,7 +500,7 @@ def make_train_step(model, opt_cfg: lars.OptConfig, schedule, *,
         state, metrics = sgd_update(state, grads, metrics, new_bn, guard_in)
         return state, metrics
 
-    metric_keys = ("loss", "aux", "acc", "lr")
+    metric_keys = ("loss", "aux", "acc") + held_stat_keys(model) + ("lr",)
     if guard:
         metric_keys = metric_keys + guard_lib.SENTINEL_KEYS
 

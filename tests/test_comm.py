@@ -402,10 +402,20 @@ def test_pack_stages_f32_keeps_bf16_wire():
 
 
 # ------------------------------------- 8-device equivalence (subprocess)
+#
+# Each script runs 8 host devices in one process. XLA:CPU runs every
+# device's part of a program on one thread pool, as many threads as the
+# machine has cores unless ``NPROC`` says otherwise; a collective holds
+# its thread until all 8 parts arrive. With 8 cores and programs in
+# flight one behind another, the pool can fill with parts of different
+# programs that wait for each other, and after 40 s XLA aborts the
+# process ("Termination timeout for `all reduce ...`"). 32 threads keep
+# room for every part.
 
 EQUIV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["NPROC"] = "32"
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
@@ -567,6 +577,7 @@ def _run_side_by_side(script, argvs, *, timeout):
 SHARD_SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["NPROC"] = "32"
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
@@ -747,6 +758,7 @@ def test_shard_update_matches_replicated_8dev():
 SHARD_STEP_SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["NPROC"] = "32"
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_mesh
 from repro import comm

@@ -24,6 +24,8 @@ EXPECTED = {
     "deepseek-v2-236b": dict(L=60, d=5120, H=128, kv=128, ff=1536,
                              V=102_400),
     "qwen2-moe-a2.7b":  dict(L=24, d=2048, H=16, kv=16, ff=1408, V=151_936),
+    "deepseek-v2-lite": dict(L=27, d=2048, H=16, kv=16, ff=1408,
+                             V=102_400),
 }
 
 

@@ -655,6 +655,7 @@ def test_kill_resume_cli_smoke(tmp_path):
 ELASTIC_SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
+os.environ["NPROC"] = "32"  # room for every device's part (tests/test_comm.py)
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_mesh
 from repro.configs import get_config

@@ -361,7 +361,9 @@ def _run_cli(argv, timeout=900):
         [sys.executable, "-m", "repro.launch.train"] + argv,
         capture_output=True, text=True, timeout=timeout,
         env={**os.environ, "PYTHONPATH": "src",
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=8"})
+             "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+             # room for every device's part (tests/test_comm.py)
+             "NPROC": "32"})
 
 
 @pytest.mark.tier2

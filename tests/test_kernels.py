@@ -238,3 +238,31 @@ def test_flash_attention_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("m,k,n,sizes", [
+    (384, 64, 96, (50, 0, 77, 100)),     # rows past the groups, an empty one
+    (200, 32, 48, (200,)),               # rows padded to a whole tile
+    (1024, 128, 128, (300, 212, 512, 0))])
+def test_grouped_matmul_vs_ragged_dot(m, k, n, sizes):
+    """The grouped-matmul kernel's product, data and weight gradients ==
+    ``jax.lax.ragged_dot``'s over the groups' rows; the rows past the
+    groups are the caller's to mask, as ``moe_held`` masks them."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+    sizes = jnp.asarray(sizes, jnp.int32)
+    a = jax.random.normal(jax.random.PRNGKey(m), (m, k))
+    w = jax.random.normal(jax.random.PRNGKey(n), (len(sizes), k, n))
+    live = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+
+    def loss(fn):
+        def f(a, w):
+            y = fn(jnp.where(live, a, 0.0), w, sizes)
+            return jnp.sum(jnp.where(live, y, 0.0) ** 2)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+
+    got = loss(lambda a, w, s: grouped_matmul(a, w, s, interpret=True))(a, w)
+    want = loss(lambda a, w, s: jax.lax.ragged_dot(
+        a, w, s, precision=jax.lax.Precision.HIGHEST))(a, w)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5 * float(
+            jnp.max(jnp.abs(r))))

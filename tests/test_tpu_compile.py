@@ -17,8 +17,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.comm.ring_kernel import ring_add_step
 from repro.core.bucketing import CHUNK
-from repro.kernels import batched_norm, flash_attention, lars_update, \
-    smoothed_xent
+from repro.kernels import batched_norm, flash_attention, grouped_matmul, \
+    lars_update, smoothed_xent
 
 pytestmark = pytest.mark.tier1
 
@@ -73,13 +73,27 @@ def _case(name):
                                   causal=True, n_q_heads=H, n_kv_heads=K,
                                   interpret=False),
                 [((H, S, D), bf16), ((K, S, D), bf16), ((K, S, D), bf16)])
+    if name.startswith("grouped_matmul"):
+        # DeepSeek-V2-Lite's held experts: one 8,192-token sequence's 6
+        # slots a token, 8 experts; the product, its data and weight
+        # gradients
+        k, n = (2048, 1408) if name.endswith("in") else (1408, 2048)
+
+        def fn(lhs, rhs, sizes):
+            def total(lhs, rhs):
+                y = grouped_matmul.grouped_matmul(lhs, rhs, sizes,
+                                                  interpret=False)
+                return jnp.sum(y.astype(f32))
+            return jax.grad(total, argnums=(0, 1))(lhs, rhs)
+        return fn, [((8192 * 6, k), bf16), ((8, k, n), bf16), ((8,), i32)]
     raise KeyError(name)
 
 
 @pytest.mark.parametrize("name", [
     "ring_add_step_f32", "ring_add_step_bf16", "batched_sumsq",
     "lars_packed_update", "smoothed_xent_rows_1000",
-    "smoothed_xent_rows_151936", "flash_attention"])
+    "smoothed_xent_rows_151936", "flash_attention", "grouped_matmul_in",
+    "grouped_matmul_out"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = _case(name)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)

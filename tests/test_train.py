@@ -159,6 +159,7 @@ def test_eval_step_runs():
 DDP_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["NPROC"] = "32"  # room for every device's part (tests/test_comm.py)
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import make_mesh
 from repro.configs import get_config
